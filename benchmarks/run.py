@@ -19,8 +19,14 @@ from __future__ import annotations
 
 import os
 
+from repro.launch.device import use_compile_cache
+
 
 def main() -> None:
+    # worker processes the storms below start fold on the host CPU backend
+    # (repro.launch.device), so they never contend for the chip this
+    # process holds
+    use_compile_cache()
     fast = os.environ.get("REPRO_BENCH_FAST", "0") == "1"
     rows: list[tuple] = []
 

@@ -16,9 +16,10 @@ anything while the twins keep matching call signatures:
   ``blk_q``/``interpret``), so any oracle call shape is a valid twin call
   shape.
 * FED303 — dispatch: ``ops.py`` must import the kernel module (the Pallas
-  route) and resolve the package-level ``INTERPRET`` toggle (the
-  interpreter route), and ``__init__.py`` must re-export from ``ops`` —
-  the one public path that dispatches to both implementations.
+  route) and resolve its mode through ``repro.kernels.interpret_mode``
+  (compiled on an accelerator, interpreted on the CPU backend), and
+  ``__init__.py`` must re-export from ``ops`` — the one public path that
+  dispatches to both implementations.
 """
 
 from __future__ import annotations
@@ -91,7 +92,7 @@ class KernelTwinRule(Rule):
         "FED301": "kernel package missing its ops/ref/kernel structure",
         "FED302": "ref oracle without a signature-compatible kernel twin",
         "FED303": "kernel package does not dispatch through ops "
-                  "(pallas import, INTERPRET toggle, __init__ re-export)",
+                  "(pallas import, interpret_mode, __init__ re-export)",
     }
 
     def __init__(self, root_rel: str = KERNELS_ROOT):
@@ -163,12 +164,13 @@ class KernelTwinRule(Rule):
             out.append(Finding(ops_src.rel, 1, "FED303",
                                f"`{name}/ops.py` does not import the kernel "
                                f"module `{kernel_mod}` (no Pallas dispatch)"))
-        if not any(isinstance(n, ast.Name) and n.id == "INTERPRET"
+        if not any(isinstance(n, ast.Call) and isinstance(n.func, ast.Name)
+                   and n.func.id == "interpret_mode"
                    for n in ast.walk(ops_src.tree)):
             out.append(Finding(ops_src.rel, 1, "FED303",
-                               f"`{name}/ops.py` never resolves the "
-                               f"`INTERPRET` toggle (no interpreter-mode "
-                               f"dispatch)"))
+                               f"`{name}/ops.py` never calls "
+                               f"`interpret_mode` (no backend dispatch "
+                               f"between compiled and interpreted)"))
         ops_mod = f"repro.kernels.{name}.ops"
         init_imports = [n for n in ast.walk(init_src.tree)
                         if isinstance(n, ast.ImportFrom)]

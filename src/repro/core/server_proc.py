@@ -66,6 +66,7 @@ from repro.core.transport import (      # noqa: F401  (re-exported: the
     WorkerTimeout,                      # are imported from here by old code)
     WorkerUnavailable,
 )
+from repro.launch.device import host_worker_main
 from repro.obs import clock
 from repro.obs.record import Telemetry, current_trace
 
@@ -355,7 +356,10 @@ class ShardWorker:
             return ["obsdumped",
                     self.tel.dump() if self.tel is not None else None]
         if op == "ping":
-            return ["pong", self.idx, sorted(self.records)]
+            import jax
+
+            return ["pong", self.idx, sorted(self.records),
+                    jax.default_backend()]
         raise ValueError(f"unknown worker op {op!r}")
 
     # -------------------------------------------------------------- read path
@@ -707,8 +711,9 @@ class ProcessWorkerHandle(Transport):
     def _start(self, seed_blob: bytes):
         self.cmd_q = self._ctx.Queue()
         self.rsp_q = self._ctx.Queue()
+        # the worker folds on the host CPU backend: the parent owns the chip
         self.proc = self._ctx.Process(
-            target=worker_main,
+            target=host_worker_main,
             args=(self.idx, self.cmd_q, self.rsp_q, seed_blob),
             daemon=True, name=f"fedccl-shard-{self.idx}")
         self.proc.start()

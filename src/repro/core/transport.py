@@ -58,6 +58,7 @@ import time
 
 from repro.checkpoint.msgpack_ckpt import packb
 from repro.checkpoint.msgpack_ckpt import unpackb_np as unpackb
+from repro.launch.device import host_only_env
 from repro.obs import clock
 from repro.obs.record import current_trace
 
@@ -183,7 +184,7 @@ class LoopbackShardServers:
             self._spawn(i, port=0)
 
     def _spawn(self, i: int, port: int):
-        env = dict(os.environ)
+        env = host_only_env()      # the parent owns the chip
         env["PYTHONPATH"] = self._src + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         proc = subprocess.Popen(

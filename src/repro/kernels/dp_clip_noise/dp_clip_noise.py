@@ -23,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE = 8 * 128 * 8  # f32 lanes per block, VPU-aligned (matches fedavg_agg)
 
@@ -45,7 +46,7 @@ def _clip_noise_kernel(s_ref, x_ref, n_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dp_clip_noise_tiled(delta: jnp.ndarray, noise: jnp.ndarray, clip,
-                        noise_multiplier, *, interpret: bool = True):
+                        noise_multiplier, *, interpret: bool):
     """delta, noise: flat (T,) f32 with T % TILE == 0.  Returns privatized
     (T,) f32: ``delta * min(1, clip/||delta||) + (noise_multiplier * clip) *
     noise``.  ``noise`` is a caller-supplied standard-normal vector so the
@@ -57,7 +58,8 @@ def dp_clip_noise_tiled(delta: jnp.ndarray, noise: jnp.ndarray, clip,
         _sumsq_kernel,
         grid=grid,
         in_specs=[vec()],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0),
+                               memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
     )(delta)
@@ -69,7 +71,8 @@ def dp_clip_noise_tiled(delta: jnp.ndarray, noise: jnp.ndarray, clip,
     return pl.pallas_call(
         _clip_noise_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0)), vec(), vec()],
+        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0),
+                               memory_space=pltpu.SMEM), vec(), vec()],
         out_specs=vec(),
         out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
         interpret=interpret,

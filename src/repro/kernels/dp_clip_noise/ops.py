@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.dp_clip_noise.dp_clip_noise import TILE, dp_clip_noise_tiled
 
 
@@ -20,7 +20,7 @@ def privatize_flat(delta: jnp.ndarray, noise: jnp.ndarray, clip,
 
     Zero padding is harmless on both passes: padded lanes contribute 0 to the
     sum of squares and the padded outputs are sliced off."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     t = delta.shape[0]
     pad = (-t) % TILE
     if pad:

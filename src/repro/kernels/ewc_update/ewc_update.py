@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE = 8 * 128 * 8
 
@@ -36,17 +37,18 @@ def _ewc_kernel(lam_ref, g_ref, p_ref, a_ref, f_ref, go_ref, loss_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ewc_tiled(lam, grads, params, anchor, fisher, *, interpret: bool = True):
+def ewc_tiled(lam, grads, params, anchor, fisher, *, interpret: bool):
     """All flat (T,) f32, T % TILE == 0.  Returns (g_out (T,), loss scalar)."""
     t = grads.shape[0]
     grid = (t // TILE,)
     vec = lambda: pl.BlockSpec((TILE,), lambda i: (i,))
+    scalar = lambda: pl.BlockSpec((1, 1), lambda i: (0, 0),
+                                  memory_space=pltpu.SMEM)
     go, loss = pl.pallas_call(
         _ewc_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                  vec(), vec(), vec(), vec()],
-        out_specs=[vec(), pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        in_specs=[scalar(), vec(), vec(), vec(), vec()],
+        out_specs=[vec(), scalar()],
         out_shape=[jax.ShapeDtypeStruct((t,), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)],
         interpret=interpret,

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.ewc_update.ewc_update import TILE, ewc_tiled
 
 
@@ -12,7 +12,7 @@ def ewc_penalty_grad_flat(lam, grads, params, anchor, fisher=None, *,
                           interpret=None):
     """Flat (T,) tensors; fisher=None means L2-SP (F=1).
     Returns (g_out, penalty_loss)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     t = grads.shape[0]
     if fisher is None:
         fisher = jnp.ones_like(grads, jnp.float32)

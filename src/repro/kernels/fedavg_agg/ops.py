@@ -9,14 +9,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.fedavg_agg.fedavg_agg import TILE, agg_tiled
 from repro.utils.tree import unflatten_params
 
 
 def aggregate_flat(stacked: jnp.ndarray, weights, *, interpret=None) -> jnp.ndarray:
     """stacked: (N, T) arbitrary T; returns (T,) f32 weighted sum."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     n, t = stacked.shape
     pad = (-t) % TILE
     if pad:

@@ -89,7 +89,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                      "interpret"))
 def flash_tiled(q, k, v, *, causal: bool, window: int, scale: float,
                 t_real: int, blk_q: int = DEFAULT_BLK_Q,
-                blk_k: int = DEFAULT_BLK_K, interpret: bool = True):
+                blk_k: int = DEFAULT_BLK_K, interpret: bool):
     """q: (B, H, S, D); k/v: (B, KV, T, D); S % blk_q == 0, T % blk_k == 0.
     Returns (B, H, S, D)."""
     B, H, S, D = q.shape

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.local_attn.local_attn import (
     DEFAULT_BLK_K,
     DEFAULT_BLK_Q,
@@ -16,7 +16,7 @@ def local_flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                           scale: float = 1.0, blk_q: int = DEFAULT_BLK_Q,
                           blk_k: int = DEFAULT_BLK_K, interpret=None):
     """q: (B, H, S, D); k/v: (B, KV, T, D).  Arbitrary S/T (padded here)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     B, H, S, D = q.shape
     T = k.shape[2]
     blk_q = min(blk_q, max(8, S))

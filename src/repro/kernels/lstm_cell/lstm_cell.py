@@ -40,7 +40,7 @@ def _lstm_kernel(x_ref, h_ref, c_ref, wx_ref, wh_ref, b_ref, ho_ref, co_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lstm_step_tiled(x, h, c, wx, wh, b, *, interpret: bool = True):
+def lstm_step_tiled(x, h, c, wx, wh, b, *, interpret: bool):
     """x: (B, I), h/c: (B, H), wx: (I, 4H), wh: (H, 4H), b: (1, 4H);
     B % BATCH_TILE == 0.  Returns (h', c')."""
     B, I = x.shape

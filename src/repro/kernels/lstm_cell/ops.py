@@ -7,12 +7,12 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.lstm_cell.lstm_cell import BATCH_TILE, lstm_step_tiled
 
 
 def lstm_cell_fused(p: dict, x, h, c, *, interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     B = x.shape[0]
     pad = (-B) % BATCH_TILE
     if pad:

@@ -13,13 +13,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_chunk.ssd_chunk import ssd_intra_chunk
 
 
 def ssd_chunked_pallas(x, dt, A, B, C, chunk: int, init_state=None, *,
                        interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     b, l, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     pad = (-l) % chunk

@@ -56,7 +56,7 @@ def _ssd_kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, st_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk(xdt, dA, B, C, *, interpret: bool = True):
+def ssd_intra_chunk(xdt, dA, B, C, *, interpret: bool):
     """xdt: (b,c,l,h,p); dA: (b,c,l,h); B,C: (b,c,l,h,n) (already head-
     broadcast).  Returns (y_diag (b,c,l,h,p), states (b,c,h,n,p))."""
     b, c, l, h, p = xdt.shape

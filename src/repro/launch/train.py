@@ -6,8 +6,8 @@ Two entry modes:
   * default: single-model LM training on synthetic data for a reduced
     assigned architecture (CPU-scale driver used by examples/tests).
 
-Real-cluster usage would launch one process per host with the production
-mesh; on this container everything runs on the host device.
+Runs on JAX's default device, with the persistent compilation cache
+placed by ``repro.launch.device.use_compile_cache``.
 """
 
 from __future__ import annotations
@@ -17,6 +17,8 @@ import json
 import time
 
 import numpy as np
+
+from repro.launch.device import use_compile_cache
 
 
 def train_lm(arch: str, steps: int, batch: int, seq: int, lr: float,
@@ -57,7 +59,8 @@ def train_federated(n_sites: int, n_days: int, rounds: int, seed: int):
 
     report = run_fedccl_solar(n_sites=n_sites, n_days=n_days, rounds=rounds,
                               seed=seed)
-    print(json.dumps(report, indent=2, default=str))
+    print(json.dumps({k: v for k, v in report.items() if k != "models"},
+                     indent=2, default=str))
     return report
 
 
@@ -74,6 +77,7 @@ def main():
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     t0 = time.time()
     if args.federated:

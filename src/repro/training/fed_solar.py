@@ -81,6 +81,12 @@ def make_train_fn(sgd_step, *, epochs: int = 3, batch_size: int = 8):
 # experiment driver
 # ---------------------------------------------------------------------------
 
+#: the fleet's two clustering spaces: site location and panel orientation
+SOLAR_SPACES = (
+    ClusterSpaceConfig("loc", eps=120.0, min_samples=2, metric="haversine"),
+    ClusterSpaceConfig("ori", eps=30.0, min_samples=2, metric="cyclic"),
+)
+
 
 def run_fedccl_solar(n_sites: int = 9, n_days: int = 60, rounds: int = 3,
                      seed: int = 0, hidden: int = 64, epochs: int = 3,
@@ -88,12 +94,17 @@ def run_fedccl_solar(n_sites: int = 9, n_days: int = 60, rounds: int = 3,
                      lr: float = 1e-2, eval_sites: str = "all",
                      dp_clip: float = None, dp_noise_multiplier: float = 1.0,
                      secure_agg: bool = False,
-                     target_delta: float = 1e-5) -> dict:
-    """One experimental run.  Returns the Table-II-shaped report dict.
+                     target_delta: float = 1e-5,
+                     use_pallas_agg: bool = False) -> dict:
+    """One experimental run.  Returns the Table-II-shaped report dict,
+    with the federation's folded ``models`` (``"global"`` and one entry per
+    cluster key) beside it.
 
     With ``dp_clip`` / ``secure_agg`` set, client updates are privatized
     (clip + Gaussian noise) and/or aggregated under pairwise masking; the
     report then carries a ``privacy`` section with (epsilon, delta) budgets.
+    ``use_pallas_agg`` routes the fold and the DP privatization through
+    the Pallas kernels.
     """
     rng = np.random.default_rng(seed)
     fleet = generate_fleet(n_sites=n_sites + n_independent, n_days=n_days,
@@ -114,13 +125,10 @@ def run_fedccl_solar(n_sites: int = 9, n_days: int = 60, rounds: int = 3,
 
     # ---- FedCCL federation over the training population
     fed_cfg = FedCCLConfig(
-        spaces=(ClusterSpaceConfig("loc", eps=120.0, min_samples=2,
-                                   metric="haversine"),
-                ClusterSpaceConfig("ori", eps=30.0, min_samples=2,
-                                   metric="cyclic")),
-        ewc_lambda=ewc_lambda, seed=seed,
+        spaces=SOLAR_SPACES, ewc_lambda=ewc_lambda, seed=seed,
         dp_clip=dp_clip, dp_noise_multiplier=dp_noise_multiplier,
-        secure_agg=secure_agg, target_delta=target_delta)
+        secure_agg=secure_agg, target_delta=target_delta,
+        use_pallas_agg=use_pallas_agg)
     fed = FedCCL(fed_cfg, init_params, train_fn)
     specs = [ClientSpec(site.site_id, site.static_features,
                         site_splits[site.site_id][1],
@@ -250,9 +258,14 @@ def run_fedccl_solar(n_sites: int = 9, n_days: int = 60, rounds: int = 3,
         "async_stats": stats,
         "privacy": fed.privacy_report(),
         "fig4_example": fig4,
+        "models": {"global": fed.store.params("global"),
+                   **{key: fed.store.params("cluster", key)
+                      for key in sorted({k for ks in assignments.values()
+                                         for k in ks})}},
         "config": {"n_sites": n_sites, "n_days": n_days, "rounds": rounds,
                    "hidden": hidden, "seed": seed,
                    "ewc_lambda": ewc_lambda, "dp_clip": dp_clip,
                    "dp_noise_multiplier": dp_noise_multiplier,
-                   "secure_agg": secure_agg},
+                   "secure_agg": secure_agg,
+                   "use_pallas_agg": use_pallas_agg},
     }

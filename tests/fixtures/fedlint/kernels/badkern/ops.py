@@ -1,5 +1,5 @@
-"""Dispatcher that neither imports the kernel module nor resolves
-INTERPRET (FED303 x2), and whose public function drops the oracle's
+"""Dispatcher that neither imports the kernel module nor calls
+interpret_mode (FED303 x2), and whose public function drops the oracle's
 ``alpha`` parameter (FED302)."""
 
 

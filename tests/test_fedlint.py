@@ -119,7 +119,7 @@ def test_kernel_twin_fixture_findings():
         ("FED302", "ref.py", 4),          # scale_ref has no twin
         ("FED303", "__init__.py", 1),     # no re-export from ops
         ("FED303", "ops.py", 1),          # no kernel-module import
-        ("FED303", "ops.py", 1),          # no INTERPRET toggle
+        ("FED303", "ops.py", 1),          # no interpret_mode call
     ]
 
 

@@ -1,6 +1,8 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
 
-(interpret=True on CPU, per the harness contract).
+(run on the CPU backend, where ``repro.kernels.interpret_mode`` picks the
+Pallas interpreter; tests/test_tpu_compile.py compiles the same kernels
+for a TPU).
 """
 
 import jax
@@ -215,3 +217,17 @@ def test_ssd_backend_switch_model_parity(monkeypatch):
     monkeypatch.setattr(S, "SSD_BACKEND", "pallas")
     out, _ = model.forward(params, tokens=toks)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
+
+
+# ------------------------------------------------------------- dispatch
+@pytest.mark.parametrize("backend,explicit,want", [
+    ("cpu", None, True), ("tpu", None, False), ("gpu", None, False),
+    ("cpu", False, False), ("tpu", True, True)])
+def test_interpret_mode_follows_backend_at_call_time(backend, explicit, want,
+                                                     monkeypatch):
+    """Interpreted only on the CPU backend, decided when a kernel is
+    called; an explicit ``interpret=`` wins."""
+    from repro import kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    assert kernels.interpret_mode(explicit) is want
