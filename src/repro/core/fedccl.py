@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import numpy as np
 
 from repro.core.aggregation import AggregationConfig
@@ -27,7 +28,7 @@ from repro.core.store import (
     ShardedModelStore,
 )
 from repro.obs.export import metrics_json, prometheus_text, write_perfetto
-from repro.obs.record import Telemetry
+from repro.obs.record import Telemetry, maybe_span
 from repro.privacy.accountant import RDPAccountant
 from repro.privacy.dp import DPConfig, DPPrivatizer
 from repro.privacy.secure_agg import PairwiseMasker
@@ -146,7 +147,10 @@ class FedCCL:
         self.accountant = (RDPAccountant(target_delta=cfg.target_delta)
                            if cfg.dp_clip is not None else None)
         agg_cfg = AggregationConfig(use_pallas=cfg.use_pallas_agg)
-        tel = (Telemetry(sample_n=cfg.trace_sample_n)
+        # every span is also a profiler annotation, on the device trace's
+        # clock; the facade reaches the sink through self.store alone
+        tel = (Telemetry(sample_n=cfg.trace_sample_n,
+                         annotate=jax.profiler.TraceAnnotation)
                if cfg.telemetry else None)
         if cfg.server_hosts:
             self.store = ProcessShardedModelStore(
@@ -293,16 +297,21 @@ class FedCCL:
 
     # ----------------------------------------------------- Predict & Evolve
     def join(self, spec: ClientSpec) -> tuple[list[str], object]:
-        """New client: immediate specialized model, then becomes participant."""
-        keys, params = self.pe.join(spec)
-        idx = len(self.clients)
-        c = Client(spec=spec, cluster_keys=keys, train_fn=self.train_fn,
-                   ewc_lambda=self.cfg.ewc_lambda,
-                   rng=np.random.default_rng(self.cfg.seed + 5000 + idx),
-                   privatizer=self._make_privatizer(spec.client_id, 3000 + idx))
-        c.local_params = params
-        self.clients.append(c)
-        self._clients_by_id[spec.client_id] = c
+        """New client: immediate specialized model, then becomes participant.
+        A ``join`` span with telemetry on (``join.cluster`` and
+        ``join.model`` inside)."""
+        with maybe_span(self.store.telemetry, "join",
+                        args={"client": spec.client_id}):
+            keys, params = self.pe.join(spec)
+            idx = len(self.clients)
+            c = Client(spec=spec, cluster_keys=keys, train_fn=self.train_fn,
+                       ewc_lambda=self.cfg.ewc_lambda,
+                       rng=np.random.default_rng(self.cfg.seed + 5000 + idx),
+                       privatizer=self._make_privatizer(spec.client_id,
+                                                        3000 + idx))
+            c.local_params = params
+            self.clients.append(c)
+            self._clients_by_id[spec.client_id] = c
         return keys, params
 
     # --------------------------------------------------------------- privacy
@@ -375,26 +384,32 @@ class FedCCL:
         return self.store.params(level, key)
 
     def model_for(self, client_id: str, level: str = "auto"):
-        client = self._clients_by_id.get(client_id)
-        if client is None:
-            known = sorted(self._clients_by_id)
-            shown = ", ".join(repr(k) for k in known[:8])
-            if len(known) > 8:
-                shown += f", ... ({len(known)} clients total)"
-            raise KeyError(f"unknown client_id {client_id!r}; "
-                           f"known clients: [{shown}]")
-        if level == "local":
-            return client.local_params, "local"
-        if level == "global":
-            return self._serve_params("global"), "global"
-        if level.startswith("cluster"):
-            if ":" in level:
-                key = level.split(":", 1)[1]
-            elif client.cluster_keys:
-                key = client.cluster_keys[0]
-            else:
-                # noise client (DBSCAN label -1): no cluster model exists,
-                # fall back to the global tier instead of crashing
+        """The parameters served to one client and the tier they came from.
+        With telemetry on, a ``serve.read`` span (tier choice and store
+        read) for the profiler and the ``serve_read_ns`` histogram."""
+        with maybe_span(self.store.telemetry, "serve.read", ring=False,
+                        hist="serve_read_ns"):
+            client = self._clients_by_id.get(client_id)
+            if client is None:
+                known = sorted(self._clients_by_id)
+                shown = ", ".join(repr(k) for k in known[:8])
+                if len(known) > 8:
+                    shown += f", ... ({len(known)} clients total)"
+                raise KeyError(f"unknown client_id {client_id!r}; "
+                               f"known clients: [{shown}]")
+            if level == "local":
+                return client.local_params, "local"
+            if level == "global":
                 return self._serve_params("global"), "global"
-            return self._serve_params("cluster", key), f"cluster:{key}"
-        return self.pe.choose_inference_model(client, serve=self._serve_params)
+            if level.startswith("cluster"):
+                if ":" in level:
+                    key = level.split(":", 1)[1]
+                elif client.cluster_keys:
+                    key = client.cluster_keys[0]
+                else:
+                    # noise client (DBSCAN label -1): no cluster model exists,
+                    # fall back to the global tier instead of crashing
+                    return self._serve_params("global"), "global"
+                return self._serve_params("cluster", key), f"cluster:{key}"
+            return self.pe.choose_inference_model(
+                client, serve=self._serve_params)

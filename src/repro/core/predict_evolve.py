@@ -17,6 +17,7 @@ import numpy as np
 from repro.core.clustering import NOISE, IncrementalDBSCAN
 from repro.core.protocol import Client, ClientSpec
 from repro.core.store import ModelStore
+from repro.obs.record import maybe_span
 
 
 @dataclass
@@ -79,20 +80,24 @@ class PredictEvolve:
     # ------------------------------------------------------------ new client
     def join(self, spec: ClientSpec) -> tuple[list[str], object]:
         """Predict phase: assign clusters, hand back the best model snapshot
-        (first cluster model if any, else global)."""
-        keys = []
-        for space in self.spaces:
-            label = self._insert(
-                space, spec.client_id,
-                np.asarray(spec.static_features[space.name], np.float64))
-            key = space.key(label)
-            if key is not None:
-                keys.append(key)
-                self.store.ensure_cluster(key)
-        if keys:
-            params, _ = self.store.request_model("cluster", keys[0])
-        else:
-            params, _ = self.store.request_model("global")
+        (first cluster model if any, else global).  With the store's
+        telemetry on, ``join.cluster`` and ``join.model`` spans."""
+        tel = self.store.telemetry
+        with maybe_span(tel, "join.cluster"):
+            keys = []
+            for space in self.spaces:
+                label = self._insert(
+                    space, spec.client_id,
+                    np.asarray(spec.static_features[space.name], np.float64))
+                key = space.key(label)
+                if key is not None:
+                    keys.append(key)
+                    self.store.ensure_cluster(key)
+        with maybe_span(tel, "join.model"):
+            if keys:
+                params, _ = self.store.request_model("cluster", keys[0])
+            else:
+                params, _ = self.store.request_model("global")
         return keys, params
 
     def choose_inference_model(self, client: Client, serve=None):

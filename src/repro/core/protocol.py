@@ -23,6 +23,7 @@ import numpy as np
 from repro.core.aggregation import ModelMeta, UpdateDelta
 from repro.core.continual import EWCState, make_anchor
 from repro.core.store import ModelStore
+from repro.obs.record import current_telemetry, maybe_span
 from repro.utils.tree import flatten_params, unflatten_params
 
 # train_fn(params, dataset, rng, anchor: EWCState|None) ->
@@ -69,12 +70,19 @@ class Client:
     local_meta: ModelMeta = field(default_factory=ModelMeta)
     _local_anchor: EWCState | None = None
 
+    def _train(self, params, anchor):
+        """One ``train_fn`` call, a ``client.train`` span when the runtime
+        put telemetry in scope."""
+        with maybe_span(current_telemetry(), "client.train",
+                        args={"client": self.spec.client_id}):
+            return self.train_fn(params, self.spec.dataset, self.rng, anchor)
+
     # ------------------------------------------------------------ local tier
     def train_local(self):
         assert self.local_params is not None, "seed local model first"
         anchor = self._local_anchor if self.ewc_lambda else None
-        new_params, n_samples, n_epochs = self.train_fn(
-            self.local_params, self.spec.dataset, self.rng, anchor)
+        new_params, n_samples, n_epochs = self._train(self.local_params,
+                                                      anchor)
         self.local_params = new_params
         self.local_meta = self.local_meta.accumulate(
             UpdateDelta(n_samples, n_epochs, 1))
@@ -108,8 +116,7 @@ class Client:
         privatizes the flat delta directly, avoiding a pytree round trip."""
         anchor = (make_anchor(fetched_params, lam=self.ewc_lambda)
                   if self.ewc_lambda else None)
-        new_params, n_samples, n_epochs = self.train_fn(
-            fetched_params, self.spec.dataset, self.rng, anchor)
+        new_params, n_samples, n_epochs = self._train(fetched_params, anchor)
         if privatize and self.privatizer is not None:
             new_params = self.privatizer.privatize(fetched_params, new_params,
                                                    model_key=model_key)

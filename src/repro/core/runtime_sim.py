@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.core.protocol import Client
 from repro.core.store import ModelStore
-from repro.obs import clock
+from repro.obs.record import maybe_span, telemetry_scope
 
 
 @dataclass(order=True)
@@ -77,6 +77,7 @@ class AsyncSimRuntime:
         if self.store.masker is not None:
             return self._run_secure(rounds_per_client)
         batched = self.store.batch_aggregation
+        tel = getattr(self.store, "telemetry", None)
         for i, c in enumerate(self.clients):
             self._push(self._duration(c) * self.rng.uniform(0, 1), "round_start", i)
 
@@ -85,6 +86,9 @@ class AsyncSimRuntime:
             ev = heapq.heappop(self._heap)
             self.now = ev.time
             client = self.clients[ev.client_idx]
+            # one span per handled event: the schedule interleaves clients,
+            # so a span across a client's whole round would overlap others'
+            args = {"client": client.spec.client_id}
 
             if ev.kind == "round_start":
                 if self.completed_rounds[client.spec.client_id] >= target:
@@ -94,41 +98,40 @@ class AsyncSimRuntime:
                     self._push(self.now + self._duration(client), "round_start",
                                ev.client_idx)
                     continue
-                # local training happens on-device, immediately
-                client.train_local()
-                # fetch snapshots NOW; training completes after a delay
-                jobs = []
-                for key in client.cluster_keys:
+                with telemetry_scope(tel), maybe_span(tel, "client.start",
+                                                      args=args):
+                    # local training happens on-device, immediately
+                    client.train_local()
+                    # fetch snapshots NOW; training completes after a delay
+                    jobs = []
+                    for key in client.cluster_keys:
+                        if batched:
+                            self.store.drain("cluster", key)
+                        p, m = client.fetch(self.store, "cluster", key)
+                        jobs.append(("cluster", key, p, m))
                     if batched:
-                        self.store.drain("cluster", key)
-                    p, m = client.fetch(self.store, "cluster", key)
-                    jobs.append(("cluster", key, p, m))
-                if batched:
-                    self.store.drain("global")
-                p, m = client.fetch(self.store, "global", None)
-                jobs.append(("global", None, p, m))
+                        self.store.drain("global")
+                    p, m = client.fetch(self.store, "global", None)
+                    jobs.append(("global", None, p, m))
                 self._push(self.now + self._duration(client), "submit",
                            ev.client_idx, jobs)
 
             elif ev.kind == "submit":
-                for level, key, p, m in ev.payload:
-                    new_p, new_meta, delta = client.train_update(
-                        p, m, self.store.model_key(level, key))
-                    # staleness vs the round at enqueue time: queued-but-
-                    # undrained updates count (in batched mode the
-                    # materialized meta lags the logical server round)
-                    cur_round = self.store.effective_round(level, key)
-                    self.staleness_log.append(cur_round - m.round)
-                    client.submit(self.store, level, key, new_p, new_meta, delta)
-                    if batched and (self.store.pending_depth(level, key)
-                                    >= self.store.max_coalesce):
-                        self.store.drain(level, key)
-                tel = getattr(self.store, "telemetry", None)
-                if tel is not None:
-                    # instantaneous marker (sim time is virtual): one event
-                    # per completed client round on the real-clock timeline
-                    tel.event("client.round", clock.monotonic_ns(), 0,
-                              args={"client": client.spec.client_id})
+                with telemetry_scope(tel), maybe_span(tel, "client.update",
+                                                      args=args):
+                    for level, key, p, m in ev.payload:
+                        new_p, new_meta, delta = client.train_update(
+                            p, m, self.store.model_key(level, key))
+                        # staleness vs the round at enqueue time: queued-but-
+                        # undrained updates count (in batched mode the
+                        # materialized meta lags the logical server round)
+                        cur_round = self.store.effective_round(level, key)
+                        self.staleness_log.append(cur_round - m.round)
+                        client.submit(self.store, level, key, new_p, new_meta,
+                                      delta)
+                        if batched and (self.store.pending_depth(level, key)
+                                        >= self.store.max_coalesce):
+                            self.store.drain(level, key)
                 self.completed_rounds[client.spec.client_id] += 1
                 if self.completed_rounds[client.spec.client_id] < target:
                     self._push(self.now + 1e-3, "round_start", ev.client_idx)
@@ -153,23 +156,29 @@ class AsyncSimRuntime:
         stray masks are recovered via seed reconstruction, the paper's
         dynamic-availability setting."""
         base = self.store.secure_round_offset
+        tel = getattr(self.store, "telemetry", None)
         for r in range(base, base + rounds):
             avail = [c for c in self.clients
                      if not (self.dropout_prob
                              and self.rng.random() < self.dropout_prob)]
             if not avail:      # degenerate draw: keep the round non-empty
                 avail = [self.clients[int(self.rng.integers(len(self.clients)))]]
-            for c in avail:
-                c.train_local()
-            for level, key, members in self._model_members():
-                participants = [c for c in avail if c in members]
-                if not participants:
-                    continue
-                expected = [c.spec.client_id for c in members]
-                for c in participants:
-                    c.secure_round_update(self.store, level, key, expected, r)
-                    self.staleness_log.append(0)   # lockstep: never stale
-                self.store.drain_secure(level, key, r, expected)
+            with telemetry_scope(tel), maybe_span(tel, "secure.round",
+                                                  args={"round": r}):
+                for c in avail:
+                    c.train_local()
+                for level, key, members in self._model_members():
+                    participants = [c for c in avail if c in members]
+                    if not participants:
+                        continue
+                    expected = [c.spec.client_id for c in members]
+                    with maybe_span(tel, "secure.model", args={
+                            "key": self.store.model_key(level, key)}):
+                        for c in participants:
+                            c.secure_round_update(self.store, level, key,
+                                                  expected, r)
+                            self.staleness_log.append(0)  # lockstep: never stale
+                        self.store.drain_secure(level, key, r, expected)
             self.now += max(self._duration(c) for c in avail)
             for c in avail:
                 self.completed_rounds[c.spec.client_id] += 1

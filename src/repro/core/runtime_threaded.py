@@ -42,6 +42,7 @@ import time
 
 from repro.core.protocol import Client
 from repro.core.store import ModelStore
+from repro.obs.record import maybe_span, telemetry_scope
 
 
 class AsyncThreadedRuntime:
@@ -91,12 +92,10 @@ class AsyncThreadedRuntime:
                 time.sleep(self.stagger * idx)
             tel = getattr(self.store, "telemetry", None)
             for _ in range(self.rounds):
-                if tel is None:
+                with telemetry_scope(tel), maybe_span(
+                        tel, "client.round",
+                        args={"client": client.spec.client_id}):
                     self._one_round(client)
-                else:
-                    with tel.span("client.round",
-                                  args={"client": client.spec.client_id}):
-                        self._one_round(client)
         except BaseException as e:  # surfaced by join()
             self.errors.append(e)
 
@@ -176,17 +175,20 @@ class AsyncThreadedRuntime:
 
         barrier = threading.Barrier(len(self.clients), action=drain_round)
 
+        tel = getattr(self.store, "telemetry", None)
+
         def loop(client: Client, idx: int):
             try:
                 if self.stagger:
                     time.sleep(self.stagger * idx)
-                for r in range(base, base + self.rounds):
-                    client.train_local()
-                    for level, key, ids in members:
-                        if client.spec.client_id in ids:
-                            client.secure_round_update(self.store, level, key,
-                                                       ids, r)
-                    barrier.wait()
+                with telemetry_scope(tel):
+                    for r in range(base, base + self.rounds):
+                        client.train_local()
+                        for level, key, ids in members:
+                            if client.spec.client_id in ids:
+                                client.secure_round_update(
+                                    self.store, level, key, ids, r)
+                        barrier.wait()
             except BaseException as e:      # surfaced by run()
                 self.errors.append(e)
                 barrier.abort()

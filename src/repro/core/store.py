@@ -54,7 +54,7 @@ import itertools
 import threading
 import zlib
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 from repro.core import server_proc, transport
 from repro.core.fetch import WireCache, serve_fetch
@@ -77,7 +77,7 @@ from repro.core.server_proc import (
     meta_to_wire,
 )
 from repro.obs import clock
-from repro.obs.record import current_trace, trace_scope
+from repro.obs.record import current_trace, maybe_span, trace_scope
 
 GLOBAL_KEY = "__global__"
 
@@ -179,6 +179,9 @@ class PendingUpdate:
     params: object
     meta: ModelMeta
     delta: UpdateDelta
+    # start of its ``enqueue`` span (repro.obs.clock), 0 = telemetry off;
+    # the drain that folds it records ``fold start - enqueued_ns``
+    enqueued_ns: int = 0
 
 
 @dataclass(frozen=True)
@@ -189,6 +192,7 @@ class PendingSecureUpdate:
     round_id: int
     masked_delta: object     # s_i * privatized_delta_i + pairwise masks
     delta: UpdateDelta
+    submitted_ns: int = 0    # submit stamp, as PendingUpdate.enqueued_ns
 
 
 class ModelRecord:
@@ -250,12 +254,15 @@ def _drain_record_once(rec: ModelRecord, max_coalesce: int,
     if not batch:
         return None
     base_round = rec.meta.round
-    t0 = clock.monotonic_ns() if tel is not None else 0
+    ups = [(u.params, u.meta, u.delta) for u in batch]
     try:
-        res = coalesced_aggregate(rec.params, rec.meta,
-                                  [(u.params, u.meta, u.delta)
-                                   for u in batch],
-                                  agg_cfg)
+        args = {"key": key, "n": len(batch)}
+        with maybe_span(tel, "fold", current_trace(), args,
+                        hist=f"drain_fold_ns_{route}") as sp:
+            if sp is not None:
+                args["waits"] = _queue_waits(
+                    sp.t0, [u.enqueued_ns for u in batch])
+            res = coalesced_aggregate(rec.params, rec.meta, ups, agg_cfg)
     except BaseException:
         # a malformed update must not strand the batch: put it back at the
         # queue head (FIFO preserved) and retire the in-flight rounds so
@@ -265,8 +272,7 @@ def _drain_record_once(rec: ModelRecord, max_coalesce: int,
             rec.inflight_rounds -= rounds
         raise
     if tel is not None:
-        dur = clock.monotonic_ns() - t0
-        tel.metrics.histogram(f"drain_fold_ns_{route}").observe(dur)
+        _observe_waits(tel, args["waits"])
         tel.metrics.histogram("coalesce_batch").observe(len(batch))
         stale = tel.metrics.histogram("staleness_at_fold")
         # telescoped staleness: ``ModelMeta.accumulate`` advances ``round``
@@ -278,45 +284,66 @@ def _drain_record_once(rec: ModelRecord, max_coalesce: int,
         for u in batch:
             stale.observe(max(0, base_round + cum - u.meta.round))
             cum += u.delta.rounds
-        tel.event("fold", t0, dur, current_trace(),
-                  {"key": key, "n": len(batch)})
     with rec.pending_lock:
         rec.swap(res.params, res.meta)
         rec.inflight_rounds -= rounds
     return res
 
 
+def _queue_waits(start_ns: int, stamps) -> list:
+    """Each update's wait in its queue: from its enqueue (a secure update's
+    submit) stamp to ``start_ns``, the start of the fold that takes it.
+    An unstamped update (queued with telemetry off) has no wait."""
+    return [start_ns - t for t in stamps if t]
+
+
+def _observe_waits(tel, waits) -> None:
+    """A fold's queue waits, into the operators' ``queue_wait_ns``."""
+    hist = tel.metrics.histogram("queue_wait_ns")
+    for w in waits:
+        hist.observe(w)
+
+
 def _drain_secure_record(rec: ModelRecord, key: str, round_id: int,
-                         expected_ids, masker,
-                         agg_cfg: AggregationConfig) -> tuple[int, int]:
-    """Fold one secure round on one record; returns (folded, recovered)."""
+                         expected_ids, masker, agg_cfg: AggregationConfig,
+                         tel=None) -> tuple[int, int]:
+    """Fold one secure round on one record; returns (folded, recovered).
+    With telemetry on, a round with updates is one ``secure_fold`` span."""
     with rec.pending_lock:
         batch = rec.secure_pending.pop(round_id, [])
     if not batch:
         return 0, 0
-    try:
-        submitted = {u.client_id for u in batch}
-        missing = sorted(set(expected_ids) - submitted)
-        correction = None
-        if missing:
-            if masker is None:
-                raise RuntimeError(
-                    "secure round has dropouts but no masker is attached "
-                    "for seed reconstruction")
-            correction = masker.reconstruct(
-                rec.params, missing, sorted(submitted), round_id, key)
-        res = secure_coalesced_aggregate(
-            rec.params, rec.meta,
-            [(u.masked_delta, u.delta) for u in batch],
-            agg_cfg, correction)
-    except BaseException:
-        # don't strand the round: restore it so a later retry can fold it
+    args = {"key": key, "n": len(batch)}
+    with maybe_span(tel, "secure_fold", current_trace(), args,
+                    hist="secure_round_ns") as sp:
+        if sp is not None:
+            args["waits"] = _queue_waits(
+                sp.t0, [u.submitted_ns for u in batch])
+        try:
+            submitted = {u.client_id for u in batch}
+            missing = sorted(set(expected_ids) - submitted)
+            correction = None
+            if missing:
+                if masker is None:
+                    raise RuntimeError(
+                        "secure round has dropouts but no masker is attached "
+                        "for seed reconstruction")
+                correction = masker.reconstruct(
+                    rec.params, missing, sorted(submitted), round_id, key)
+            res = secure_coalesced_aggregate(
+                rec.params, rec.meta,
+                [(u.masked_delta, u.delta) for u in batch],
+                agg_cfg, correction)
+        except BaseException:
+            # don't strand the round: restore it so a later retry can fold it
+            with rec.pending_lock:
+                rec.secure_pending[round_id] = \
+                    batch + rec.secure_pending.get(round_id, [])
+            raise
         with rec.pending_lock:
-            rec.secure_pending[round_id] = \
-                batch + rec.secure_pending.get(round_id, [])
-        raise
-    with rec.pending_lock:
-        rec.swap(res.params, res.meta)
+            rec.swap(res.params, res.meta)
+    if tel is not None:
+        _observe_waits(tel, args["waits"])
     return len(batch), len(missing)
 
 
@@ -602,14 +629,10 @@ class _StoreBase(_RegistryBase):
                                        updated_meta, delta, blocking=blocking)
         n = next(self._submit_seq)
         trace = (n + 1) if tel.sampled(n) else 0
-        t0 = clock.monotonic_ns()
-        with trace_scope(trace):
-            ok = self._handle_update(level, cluster_key, updated_params,
-                                     updated_meta, delta, blocking=blocking)
-        dur = clock.monotonic_ns() - t0
-        tel.metrics.histogram("submit_latency_ns").observe(dur)
-        tel.event("submit", t0, dur, trace, {"level": level})
-        return ok
+        with tel.span("submit", trace, {"level": level},
+                      hist="submit_latency_ns"), trace_scope(trace):
+            return self._handle_update(level, cluster_key, updated_params,
+                                       updated_meta, delta, blocking=blocking)
 
     def _handle_update(self, level: str, cluster_key: str | None,
                        updated_params, updated_meta: ModelMeta,
@@ -641,15 +664,17 @@ class _StoreBase(_RegistryBase):
         st = self._submit_stats(key)
         st.count_enqueue()          # before publish — see _SubmitStats
         tel = self._tel
-        t0 = clock.monotonic_ns() if tel is not None else 0
-        with rec.pending_lock:
-            rec.pending.append(upd)
-            depth = len(rec.pending)
-        st.observe_depth(depth)
-        if tel is not None:
-            tel.metrics.histogram("queue_depth").observe(depth)
-            tel.event("enqueue", t0, clock.monotonic_ns() - t0,
-                      current_trace(), {"key": key, "depth": depth})
+        args = {"key": key}
+        with maybe_span(tel, "enqueue", current_trace(), args) as sp:
+            if sp is not None:
+                upd = replace(upd, enqueued_ns=sp.t0)
+            with rec.pending_lock:
+                rec.pending.append(upd)
+                depth = len(rec.pending)
+            st.observe_depth(depth)
+            if sp is not None:
+                tel.metrics.histogram("queue_depth").observe(depth)
+                args["depth"] = depth
         return depth
 
     def enqueue_update(self, level: str, cluster_key: str | None,
@@ -677,17 +702,16 @@ class _StoreBase(_RegistryBase):
         if not ups:
             return 0
         tel = self._tel
-        t0 = clock.monotonic_ns() if tel is not None else 0
-        if self.batch_aggregation:
-            depth = self._enqueue_many(level, cluster_key, ups)
-        else:
-            for p, m, d in ups:
-                self._handle_update(level, cluster_key, p, m, d)
-            depth = 0
+        with maybe_span(tel, "submit_many", current_trace(),
+                        {"level": level, "n": len(ups)}):
+            if self.batch_aggregation:
+                depth = self._enqueue_many(level, cluster_key, ups)
+            else:
+                for p, m, d in ups:
+                    self._handle_update(level, cluster_key, p, m, d)
+                depth = 0
         if tel is not None:
             tel.metrics.histogram("submit_batch").observe(len(ups))
-            tel.event("submit_many", t0, clock.monotonic_ns() - t0,
-                      current_trace(), {"level": level, "n": len(ups)})
         return depth
 
     def _enqueue_many(self, level: str, cluster_key: str | None,
@@ -697,9 +721,15 @@ class _StoreBase(_RegistryBase):
         The base path covers every record-queued key (flat store, and the
         sharded store's cluster tier — ``_submit_stats`` routes the batch
         to the owning shard's sink)."""
+        stamp = self._enqueue_stamp()
         return self._enqueue_record_many(
             self._key(level, cluster_key),
-            [PendingUpdate(p, m, d) for p, m, d in ups])
+            [PendingUpdate(p, m, d, stamp) for p, m, d in ups])
+
+    def _enqueue_stamp(self) -> int:
+        """A batched enqueue's stamp for each of its updates (0 = telemetry
+        off), as ``_enqueue_record`` stamps one update."""
+        return clock.monotonic_ns() if self._tel is not None else 0
 
     def _enqueue_record_many(self, key: str, pend: list) -> int:
         rec = self._record(key)
@@ -765,10 +795,11 @@ class _StoreBase(_RegistryBase):
         rec = self._record(key)
         st = self._submit_stats(key)
         st.count_enqueue()          # before publish — see _SubmitStats
+        stamp = self._enqueue_stamp()
         with rec.pending_lock:
             bucket = rec.secure_pending.setdefault(round_id, [])
             bucket.append(PendingSecureUpdate(client_id, round_id,
-                                              masked_delta, delta))
+                                              masked_delta, delta, stamp))
             depth = len(bucket)
         st.observe_depth(depth)
         return depth
@@ -784,18 +815,12 @@ class _StoreBase(_RegistryBase):
         """
         key = self._key(level, cluster_key)
         rec = self._record(key)
-        tel = self._tel
-        t0 = clock.monotonic_ns() if tel is not None else 0
         with rec.lock:
             folded, recovered = _drain_secure_record(
-                rec, key, round_id, expected_ids, self.masker, self.agg_cfg)
+                rec, key, round_id, expected_ids, self.masker, self.agg_cfg,
+                self._tel)
         if not folded:
             return 0
-        if tel is not None:
-            dur = clock.monotonic_ns() - t0
-            tel.metrics.histogram("secure_round_ns").observe(dur)
-            tel.event("secure_fold", t0, dur, current_trace(),
-                      {"key": key, "n": folded})
         self._count_drain(folded, 0, secure=True, recovered=recovered)
         return folded
 
@@ -1040,15 +1065,17 @@ class ShardedModelStore(_StoreBase):
         sh = self._shards[seq % self.n_shards]
         sh.stats.count_enqueue()    # before publish — see _SubmitStats
         tel = self._tel
-        t0 = clock.monotonic_ns() if tel is not None else 0
-        with sh.lock:
-            sh.global_pending.append((seq, upd))
-            depth = len(sh.global_pending)
-        sh.stats.observe_depth(depth)
-        if tel is not None:
-            tel.metrics.histogram("queue_depth").observe(depth)
-            tel.event("enqueue", t0, clock.monotonic_ns() - t0,
-                      current_trace(), {"key": GLOBAL_KEY, "depth": depth})
+        args = {"key": GLOBAL_KEY}
+        with maybe_span(tel, "enqueue", current_trace(), args) as sp:
+            if sp is not None:
+                upd = replace(upd, enqueued_ns=sp.t0)
+            with sh.lock:
+                sh.global_pending.append((seq, upd))
+                depth = len(sh.global_pending)
+            sh.stats.observe_depth(depth)
+            if sp is not None:
+                tel.metrics.histogram("queue_depth").observe(depth)
+                args["depth"] = depth
         return depth
 
     def _enqueue_many(self, level: str, cluster_key: str | None,
@@ -1060,9 +1087,11 @@ class ShardedModelStore(_StoreBase):
         # one pass, preserving arrival seq order (the two-level fold sorts
         # by seq, so the fold is identical to N single enqueues)
         per: list[list] = [[] for _ in range(self.n_shards)]
+        stamp = self._enqueue_stamp()
         for p, m, d in ups:
             seq = next(self._gseq)
-            per[seq % self.n_shards].append((seq, PendingUpdate(p, m, d)))
+            per[seq % self.n_shards].append(
+                (seq, PendingUpdate(p, m, d, stamp)))
         tel = self._tel
         depth = 0
         for sh, items in zip(self._shards, per, strict=True):
@@ -1120,11 +1149,12 @@ class ShardedModelStore(_StoreBase):
         rec = self._record(GLOBAL_KEY)
         with rec.lock:
             with rec.pending_lock:
-                batches, seqs, total_rounds = [], [], 0
+                popped, batches, seqs, total_rounds = [], [], [], 0
                 for sh in self._shards:
                     with sh.lock:
                         items = list(sh.global_pending)
                         sh.global_pending.clear()
+                    popped.append(items)
                     seqs.append([s for s, _ in items])
                     batches.append([(u.params, u.meta, u.delta)
                                     for _, u in items])
@@ -1136,26 +1166,29 @@ class ShardedModelStore(_StoreBase):
                     rec.inflight_rounds -= total_rounds
                 return 0
             tel = self._tel
-            t0 = clock.monotonic_ns() if tel is not None else 0
             try:
-                res = two_level_coalesced_aggregate(
-                    rec.params, rec.meta, batches, self.agg_cfg,
-                    seqs=seqs, max_width=self.max_coalesce)
+                args = {"key": GLOBAL_KEY, "n": n}
+                with maybe_span(tel, "fold", current_trace(), args,
+                                hist=f"drain_fold_ns_{self._route}") as sp:
+                    if sp is not None:
+                        args["waits"] = _queue_waits(
+                            sp.t0, [u.enqueued_ns for items in popped
+                                    for _, u in items])
+                    res = two_level_coalesced_aggregate(
+                        rec.params, rec.meta, batches, self.agg_cfg,
+                        seqs=seqs, max_width=self.max_coalesce)
             except BaseException:
-                # restore the popped slices (seq tags intact, FIFO per
-                # shard) and retire the in-flight rounds before surfacing
+                # restore the popped slices (seq tags and stamps intact,
+                # FIFO per shard) and retire the in-flight rounds before
+                # surfacing
                 with rec.pending_lock:
-                    for sh, batch, sq in zip(self._shards, batches, seqs, strict=True):
-                        items = [(s, PendingUpdate(*u))
-                                 for s, u in zip(sq, batch, strict=True)]
+                    for sh, items in zip(self._shards, popped, strict=True):
                         with sh.lock:
                             sh.global_pending.extendleft(reversed(items))
                     rec.inflight_rounds -= total_rounds
                 raise
             if tel is not None:
-                dur = clock.monotonic_ns() - t0
-                tel.metrics.histogram(
-                    f"drain_fold_ns_{self._route}").observe(dur)
+                _observe_waits(tel, args["waits"])
                 tel.metrics.histogram("coalesce_batch").observe(n)
                 stale = tel.metrics.histogram("staleness_at_fold")
                 base_round = rec.meta.round
@@ -1169,8 +1202,6 @@ class ShardedModelStore(_StoreBase):
                         for s, u in zip(sq, b, strict=True)):
                     stale.observe(max(0, base_round + cum - m.round))
                     cum += d.rounds
-                tel.event("fold", t0, dur, current_trace(),
-                          {"key": GLOBAL_KEY, "n": n})
             with rec.pending_lock:
                 rec.swap(res.params, res.meta)
                 rec.inflight_rounds -= total_rounds

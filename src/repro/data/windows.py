@@ -59,9 +59,13 @@ def split_windows(windows: dict, train_frac: float = 0.8, seed: int = 0,
     return tr, te
 
 
-def batch_iter(windows: dict, batch_size: int, rng: np.random.Generator):
-    n = len(windows["target"])
+def batch_order(n: int, batch_size: int, rng: np.random.Generator):
+    """One epoch's batches of ``n`` windows as index arrays: a fresh
+    permutation cut into runs of ``batch_size`` (the last may be shorter)."""
     order = rng.permutation(n)
-    for i in range(0, n, batch_size):
-        sel = order[i:i + batch_size]
+    return [order[i:i + batch_size] for i in range(0, n, batch_size)]
+
+
+def batch_iter(windows: dict, batch_size: int, rng: np.random.Generator):
+    for sel in batch_order(len(windows["target"]), batch_size, rng):
         yield {k: v[sel] for k, v in windows.items()}

@@ -26,19 +26,29 @@ from repro.obs.export import (
     write_perfetto,
 )
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.record import Telemetry, current_trace, trace_scope
+from repro.obs.record import (
+    Telemetry,
+    current_telemetry,
+    current_trace,
+    maybe_span,
+    telemetry_scope,
+    trace_scope,
+)
 
 __all__ = [
     "MetricsRegistry",
     "Telemetry",
     "clock",
+    "current_telemetry",
     "current_trace",
     "export",
+    "maybe_span",
     "metrics",
     "metrics_json",
     "perfetto_trace",
     "prometheus_text",
     "record",
+    "telemetry_scope",
     "trace_scope",
     "write_perfetto",
 ]

@@ -13,6 +13,12 @@ ring, taken only so snapshots from other threads see a consistent view);
 oldest events when full and count the overwrites (``dropped``), so a storm
 degrades the trace, never the workload.
 
+A ``Telemetry`` may carry an annotation hook (``annotate``): a callable
+from a name to a context manager, such as ``jax.profiler.TraceAnnotation``.
+Every span then also enters ``fedccl.<name>``, so a profiler records it on
+its host timeline, on the same clock as the device's programs.  The hook
+is passed in by the caller: this package imports no JAX.
+
 The trace context is a module-level thread-local: the store's submit path
 sets it for the duration of one submit (``trace_scope``), and anything
 downstream on the same thread — the TCP transport framing a message, the
@@ -25,12 +31,21 @@ it around dispatch.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 
 from repro.obs import clock
 from repro.obs.metrics import MetricsRegistry
 
 _TLS = threading.local()
+
+#: events each recording thread keeps before overwriting its oldest: the
+#: busiest traced benchmark window (51 s of asynchronous fleet rounds on
+#: one thread, ``fleet-async``) recorded at most 5,434 (PERF.md)
+RING_CAP = 16384
+
+#: prefix of every span's profiler annotation
+ANNOTATION_PREFIX = "fedccl."
 
 
 def current_trace() -> int:
@@ -56,6 +71,46 @@ class trace_scope:
     def __exit__(self, *exc):
         _TLS.trace = self.prev
         return False
+
+
+def current_telemetry():
+    """The ``Telemetry`` a runtime put in scope on this thread (None = off).
+    Code below the runtime — training, privacy — reads it here instead of
+    taking it as an argument, so its signatures stay as they are."""
+    return getattr(_TLS, "tel", None)
+
+
+class telemetry_scope:
+    """``with telemetry_scope(tel):`` — make ``tel`` this thread's
+    ``current_telemetry()``, restoring the previous one on exit."""
+
+    __slots__ = ("tel", "prev")
+
+    def __init__(self, tel):
+        self.tel = tel
+
+    def __enter__(self):
+        self.prev = current_telemetry()
+        _TLS.tel = self.tel
+        return self
+
+    def __exit__(self, *exc):
+        _TLS.tel = self.prev
+        return False
+
+
+#: what ``maybe_span`` returns with telemetry off: a reusable no-op context
+_OFF = contextlib.nullcontext()
+
+
+def maybe_span(tel, name: str, trace: int = 0, args: dict | None = None, *,
+               hist: str | None = None, ring: bool = True):
+    """``tel.span(...)``, or with ``tel`` None (telemetry off) a shared
+    no-op context that reads no clock and enters no hook; ``with ... as
+    sp`` then binds None."""
+    if tel is None:
+        return _OFF
+    return tel.span(name, trace, args, hist=hist, ring=ring)
 
 
 class _Ring:
@@ -97,14 +152,16 @@ class Telemetry:
     ``None`` and their hot paths pay a single attribute check (the
     compiled-out fast path).  ``sample_n`` thins the *trace* dimension
     (every Nth submit gets a nonzero trace id and a cross-boundary span
-    chain); metrics and events are always recorded.
+    chain); metrics and events are always recorded.  ``annotate`` is the
+    profiler hook (module docstring); None records for the rings alone.
     """
 
-    def __init__(self, sample_n: int = 1, ring_cap: int = 4096,
-                 site: str = "parent"):
+    def __init__(self, sample_n: int = 1, ring_cap: int = RING_CAP,
+                 site: str = "parent", annotate=None):
         self.sample_n = max(int(sample_n), 1)
         self.ring_cap = int(ring_cap)
         self.site = site
+        self.annotate = annotate
         self.metrics = MetricsRegistry()
         self.anchor = clock.wall_anchor()
         self._rings: list[_Ring] = []
@@ -131,26 +188,42 @@ class Telemetry:
                              threading.get_ident(), args))
 
     class _Span:
-        __slots__ = ("tel", "name", "trace", "args", "t0")
+        __slots__ = ("tel", "name", "trace", "args", "hist", "ring", "ann",
+                     "t0")
 
-        def __init__(self, tel, name, trace, args):
+        def __init__(self, tel, name, trace, args, hist, ring):
             self.tel, self.name, self.trace, self.args = \
                 tel, name, trace, args
+            self.hist, self.ring = hist, ring
 
         def __enter__(self):
+            hook = self.tel.annotate
+            self.ann = None
+            if hook is not None:
+                self.ann = hook(ANNOTATION_PREFIX + self.name)
+                self.ann.__enter__()
             self.t0 = clock.monotonic_ns()
             return self
 
         def __exit__(self, *exc):
             t0 = self.t0
-            self.tel.event(self.name, t0, clock.monotonic_ns() - t0,
-                           self.trace, self.args)
+            dur = clock.monotonic_ns() - t0
+            if self.ann is not None:
+                self.ann.__exit__(*exc)
+            if self.hist is not None:
+                self.tel.metrics.histogram(self.hist).observe(dur)
+            if self.ring:
+                self.tel.event(self.name, t0, dur, self.trace, self.args)
             return False
 
-    def span(self, name: str, trace: int = 0, args: dict | None = None):
-        """``with tel.span("drain.fold", trace=t):`` — time a block and
-        record it as one event."""
-        return Telemetry._Span(self, name, trace, args)
+    def span(self, name: str, trace: int = 0, args: dict | None = None, *,
+             hist: str | None = None, ring: bool = True):
+        """``with tel.span("fold", trace=t) as sp:`` — time a block and
+        record it as one event; ``sp.t0`` is its start and ``sp.args`` may
+        be filled in before the block ends.  ``hist`` also observes the
+        duration in that histogram; ``ring=False`` keeps a span too
+        frequent for the rings out of them (profiler and histogram only)."""
+        return Telemetry._Span(self, name, trace, args, hist, ring)
 
     # ------------------------------------------------------------------ dump
     def events(self) -> list:

@@ -23,6 +23,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.obs.record import current_telemetry, maybe_span
 from repro.utils.tree import flatten_params, unflatten_params
 
 
@@ -49,23 +50,26 @@ class DPPrivatizer:
     def privatize_delta(self, delta_flat, model_key: str = "__global__"):
         """Clip + noise one flat update delta and record the release with
         the accountant.  The flat form is the secure-aggregation fast path:
-        masking happens in the same flat domain, so no pytree round trip."""
-        key = jax.random.fold_in(self._base_key, self._step)
-        self._step += 1
-        noise = jax.random.normal(key, delta_flat.shape, jnp.float32)
-        if self.cfg.use_pallas:
-            from repro.kernels.dp_clip_noise.ops import privatize_flat
+        masking happens in the same flat domain, so no pytree round trip.
+        A ``dp.release`` span where a runtime put telemetry in scope."""
+        with maybe_span(current_telemetry(), "dp.release",
+                        args={"client": self.client_id, "key": model_key}):
+            key = jax.random.fold_in(self._base_key, self._step)
+            self._step += 1
+            noise = jax.random.normal(key, delta_flat.shape, jnp.float32)
+            if self.cfg.use_pallas:
+                from repro.kernels.dp_clip_noise.ops import privatize_flat
 
-            priv = privatize_flat(delta_flat, noise, self.cfg.clip,
-                                  self.cfg.noise_multiplier)
-        else:
-            from repro.kernels.dp_clip_noise.ref import dp_clip_noise_ref
+                priv = privatize_flat(delta_flat, noise, self.cfg.clip,
+                                      self.cfg.noise_multiplier)
+            else:
+                from repro.kernels.dp_clip_noise.ref import dp_clip_noise_ref
 
-            priv = dp_clip_noise_ref(delta_flat, noise, self.cfg.clip,
-                                     self.cfg.noise_multiplier)
-        if self.accountant is not None:
-            self.accountant.record(self.client_id, model_key,
-                                   self.cfg.noise_multiplier)
+                priv = dp_clip_noise_ref(delta_flat, noise, self.cfg.clip,
+                                         self.cfg.noise_multiplier)
+            if self.accountant is not None:
+                self.accountant.record(self.client_id, model_key,
+                                       self.cfg.noise_multiplier)
         return priv
 
     def privatize(self, fetched_params, new_params, model_key: str = "__global__"):

@@ -47,6 +47,7 @@ import zlib
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.record import current_telemetry, maybe_span
 from repro.utils.tree import flatten_params, unflatten_params
 
 
@@ -90,10 +91,17 @@ class PairwiseMasker:
     def mask_delta_flat(self, delta_flat, client_id: str, participants,
                         round_id: int, model_key: str, weight: float):
         """Client-side masking in the flat domain:
-        ``weight * delta + signed masks``."""
-        return delta_flat * jnp.float32(weight) + jnp.asarray(
-            self.mask_flat(client_id, participants, round_id, model_key,
-                           delta_flat.shape[0]))
+        ``weight * delta + signed masks``; a ``mask`` span, with the mask's
+        upload counted in ``h2d_bytes``, where a runtime put telemetry in
+        scope."""
+        tel = current_telemetry()
+        with maybe_span(tel, "mask",
+                        args={"client": client_id, "key": model_key}):
+            mask = self.mask_flat(client_id, participants, round_id,
+                                  model_key, delta_flat.shape[0])
+            if tel is not None:
+                tel.metrics.counter("h2d_bytes").inc(mask.nbytes)
+            return delta_flat * jnp.float32(weight) + jnp.asarray(mask)
 
     def mask_update(self, base_params, new_params, client_id: str,
                     participants, round_id: int, model_key: str,
@@ -125,9 +133,14 @@ class PairwiseMasker:
     def reconstruct(self, template_params, missing_ids, survivor_ids,
                     round_id: int, model_key: str):
         """Pytree convenience over ``reconstruct_flat``, shaped like
-        ``template_params``."""
-        t = flatten_params(template_params).shape[0]
-        return unflatten_params(
-            jnp.asarray(self.reconstruct_flat(t, missing_ids, survivor_ids,
-                                              round_id, model_key)),
-            template_params)
+        ``template_params``; a ``reconstruct`` span, with the upload counted
+        in ``h2d_bytes``, where a runtime put telemetry in scope."""
+        tel = current_telemetry()
+        with maybe_span(tel, "reconstruct",
+                        args={"key": model_key, "missing": len(missing_ids)}):
+            t = flatten_params(template_params).shape[0]
+            total = self.reconstruct_flat(t, missing_ids, survivor_ids,
+                                          round_id, model_key)
+            if tel is not None:
+                tel.metrics.counter("h2d_bytes").inc(total.nbytes)
+            return unflatten_params(jnp.asarray(total), template_params)
