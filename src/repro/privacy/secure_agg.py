@@ -92,8 +92,8 @@ class PairwiseMasker:
                         round_id: int, model_key: str, weight: float):
         """Client-side masking in the flat domain:
         ``weight * delta + signed masks``; a ``mask`` span, with the mask's
-        upload counted in ``h2d_bytes``, where a runtime put telemetry in
-        scope."""
+        upload counted in ``h2d_bytes`` and ``h2d_transfers``, where a
+        runtime put telemetry in scope."""
         tel = current_telemetry()
         with maybe_span(tel, "mask",
                         args={"client": client_id, "key": model_key}):
@@ -101,6 +101,7 @@ class PairwiseMasker:
                                   model_key, delta_flat.shape[0])
             if tel is not None:
                 tel.metrics.counter("h2d_bytes").inc(mask.nbytes)
+                tel.metrics.counter("h2d_transfers").inc()
             return delta_flat * jnp.float32(weight) + jnp.asarray(mask)
 
     def mask_update(self, base_params, new_params, client_id: str,
@@ -134,7 +135,8 @@ class PairwiseMasker:
                     round_id: int, model_key: str):
         """Pytree convenience over ``reconstruct_flat``, shaped like
         ``template_params``; a ``reconstruct`` span, with the upload counted
-        in ``h2d_bytes``, where a runtime put telemetry in scope."""
+        in ``h2d_bytes`` and ``h2d_transfers``, where a runtime put
+        telemetry in scope."""
         tel = current_telemetry()
         with maybe_span(tel, "reconstruct",
                         args={"key": model_key, "missing": len(missing_ids)}):
@@ -143,4 +145,5 @@ class PairwiseMasker:
                                           round_id, model_key)
             if tel is not None:
                 tel.metrics.counter("h2d_bytes").inc(total.nbytes)
+                tel.metrics.counter("h2d_transfers").inc()
             return unflatten_params(jnp.asarray(total), template_params)

@@ -13,6 +13,8 @@ plus the §IV.E population-independent evaluation on held-out sites.
 
 from __future__ import annotations
 
+import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -64,15 +66,36 @@ def make_solar_fns(forecaster: SolarForecaster, lr: float = 5e-3,
 UPLOADED = ("history", "forecast", "target")
 
 
+@functools.partial(jax.jit, static_argnames=("sizes", "shapes"))
+def cut_batches(staged: dict, sizes: tuple, shapes: tuple) -> tuple:
+    """An epoch's arrays, already on the device in the epoch's order, cut
+    into its batches of the given ``sizes``: one dict per batch.  Each
+    array arrives flat and gets back its windows' shape, ``dict(shapes)
+    [k]`` after the window axis, here on the device."""
+    n, shapes = sum(sizes), dict(shapes)
+    staged = {k: v.reshape((n,) + shapes[k]) for k, v in staged.items()}
+    starts = itertools.accumulate(sizes, initial=0)
+    return tuple({k: v[s:s + size] for k, v in staged.items()}
+                 for s, size in zip(starts, sizes))
+
+
 def make_train_fn(sgd_step, *, epochs: int = 3, batch_size: int = 8):
     """Adapts the jitted sgd into the FedCCL protocol's train_fn.
 
+    Each epoch uploads its windows once: the ``UPLOADED`` arrays are
+    gathered on the host in the order ``batch_order`` draws, uploaded whole
+    and flat (the device, not the host, lays the windows out in its tiles)
+    and cut into the epoch's batches on the device (``cut_batches``), so a
+    step dispatches ``sgd_step`` alone.  Every batch holds the windows that
+    ``windows[k][sel]`` holds.
+
     Where a runtime put telemetry in scope (``repro.obs.record.
-    current_telemetry``), each step is a ``train.step`` span for the
-    profiler and the ``train_step_host_ns`` histogram: the batch's slicing,
-    its upload and the step's dispatch, with no wait on the device.  The
-    ``windows_trained`` and ``h2d_bytes`` counters count the windows and
-    the bytes uploaded."""
+    current_telemetry``), each epoch's staging is a ``train.stage`` span
+    and each step's dispatch a ``train.step`` span, both for the profiler
+    and a histogram (``train_stage_host_ns``, ``train_step_host_ns``), with
+    no wait on the device.  The ``windows_trained``, ``h2d_bytes`` and
+    ``h2d_transfers`` counters count the windows, the bytes uploaded and
+    the uploads."""
 
     def train_fn(params, dataset, rng: np.random.Generator, anchor):
         windows = dataset
@@ -80,17 +103,25 @@ def make_train_fn(sgd_step, *, epochs: int = 3, batch_size: int = 8):
         anchor_params = anchor.anchor if anchor is not None else None
         lam = jnp.float32(anchor.lam if anchor is not None else 0.0)
         tel = current_telemetry()
+        shapes = tuple((k, windows[k].shape[1:]) for k in UPLOADED)
         for _ in range(epochs):
-            for sel in batch_order(n, batch_size, rng):
+            with maybe_span(tel, "train.stage", ring=False,
+                            hist="train_stage_host_ns"):
+                sels = batch_order(n, batch_size, rng)
+                order = np.concatenate(sels)
+                host = {k: np.take(windows[k], order, axis=0).ravel()
+                        for k in UPLOADED}
+                if tel is not None:
+                    tel.metrics.counter("h2d_bytes").inc(
+                        sum(v.nbytes for v in host.values()))
+                    tel.metrics.counter("h2d_transfers").inc(len(host))
+                batches = cut_batches(
+                    {k: jnp.asarray(v) for k, v in host.items()},
+                    tuple(len(sel) for sel in sels), shapes)
+            for batch in batches:
                 with maybe_span(tel, "train.step", ring=False,
                                 hist="train_step_host_ns"):
-                    batch = {k: windows[k][sel] for k in UPLOADED}
-                    if tel is not None:
-                        tel.metrics.counter("h2d_bytes").inc(
-                            sum(v.nbytes for v in batch.values()))
-                    params, _ = sgd_step(
-                        params, {k: jnp.asarray(v) for k, v in batch.items()},
-                        anchor_params, lam)
+                    params, _ = sgd_step(params, batch, anchor_params, lam)
         if tel is not None:
             tel.metrics.counter("windows_trained").inc(n * epochs)
         return params, n * epochs, epochs
