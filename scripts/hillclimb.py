@@ -173,30 +173,6 @@ def c2():
                    verbose=False)
 
 
-@variant("C3_capacity_1_0")
-def c3():
-    """H: MoE capacity factor 1.25 -> 1.0 cuts routed-expert compute by
-    20% (top-6 of 64 is already balanced on synthetic data; drops are
-    acceptable in fine-tuning) — compute term down ~proportionally."""
-    os.environ["REPRO_FLASH_THRESHOLD"] = "1024"
-    import repro.configs as C
-    from repro.launch import dryrun as dr
-    import dataclasses
-
-    real_get = C.get_config
-
-    def patched(arch):
-        cfg = real_get(arch)
-        if cfg.moe:
-            cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
-                                                      capacity_factor=1.0))
-        return cfg
-
-    dr.get_config = patched
-    return dr.run_one("deepseek-moe-16b", "train_4k", remat="dots_saveable",
-                      verbose=False)
-
-
 @variant("C4_expert_over_full_mesh")
 def c4():
     """H: 64 experts over (data x model)=256 won't divide (64 < 256 uses
@@ -247,28 +223,14 @@ def d2():
 @variant("C5_best_combo")
 def c5():
     """H: combine the confirmed wins under a memory-feasible policy:
-    remat=full (C1's dots_saveable exploded temps 45->260 GB), capacity 1.0
-    (-11% compute, C3), sequence parallelism + microbatch 4 (A4/A6 lesson)
-    to push temp under ~12 GB.  Predict: compute ~0.47 s (full-remat mult
-    4/3 of C3's 0.356), temp ~45/(16*4)+overheads ~= 5-10 GB."""
+    remat=full (C1's dots_saveable exploded temps 45->260 GB), sequence
+    parallelism + microbatch 4 (A4/A6 lesson) to push temp under ~12 GB.
+    Routing is dropless, so there is no capacity factor to trade."""
     os.environ["REPRO_FLASH_THRESHOLD"] = "1024"
-    import dataclasses
-    import repro.configs as C
-    from repro.launch import dryrun as dr
-
-    real_get = C.get_config
-
-    def patched(arch):
-        cfg = real_get(arch)
-        if cfg.moe:
-            cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
-                                                      capacity_factor=1.0))
-        return cfg
-
-    dr.get_config = patched
-    return dr.run_one("deepseek-moe-16b", "train_4k", remat="full",
-                      extra_rules={"seq": "model"}, n_microbatches=4,
-                      verbose=False)
+    from repro.launch.dryrun import run_one
+    return run_one("deepseek-moe-16b", "train_4k", remat="full",
+                   extra_rules={"seq": "model"}, n_microbatches=4,
+                   verbose=False)
 
 
 def main():

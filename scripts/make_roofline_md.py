@@ -11,7 +11,7 @@ NOTES = {
 SPECIFIC = {
     ("deepseek-v3-671b", "decode_32k"): "617 MB/step of all-gathers: FSDP param gathers over `data` are pure overhead at decode — reshard params to `model`-only (see §Perf B)",
     ("internvl2-76b", "train_4k"): "12 s compute term is remat-dominated (mult 4x) and the unfused sdpa path blows temp memory to 261 GB — flash + dots_saveable (see §Perf A)",
-    ("deepseek-moe-16b", "train_4k"): "all-reduce 9.9 GB/step dominates collectives (grad sync over data); capacity-factor and remat tuning move compute (see §Perf C)",
+    ("deepseek-moe-16b", "train_4k"): "all-reduce 9.9 GB/step dominates collectives (grad sync over data); remat tuning moves compute (see §Perf C)",
     ("recurrentgemma-9b", "prefill_32k"): "19 GB of all-reduce from activation-sharding mismatches between recurrent and local-attn blocks",
 }
 

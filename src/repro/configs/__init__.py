@@ -28,6 +28,7 @@ ARCH_REGISTRY: dict[str, str] = {
     "deepseek-moe-16b": "deepseek_moe_16b",
     "glm4-9b": "glm4_9b",
     "deepseek-7b": "deepseek_7b",
+    "kanana2-30b-a3b": "kanana2_30b_a3b",
 }
 
 ALL_ARCHS = tuple(ARCH_REGISTRY)

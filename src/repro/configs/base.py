@@ -30,18 +30,34 @@ class MoEConfig:
     router_aux_coef: float = 0.001    # load-balance auxiliary loss coefficient
     routed_scaling: float = 1.0       # DeepSeek-V3 routed-expert output scale
     score_func: str = "softmax"       # softmax | sigmoid (DSv3 uses sigmoid)
-    capacity_factor: float = 1.25     # GShard token-capacity multiplier
+    # the routed experts this chip holds (expert parallelism): experts
+    # ``first_held .. first_held + n_held - 1`` of ``n_routed_experts``.
+    # The router keeps all ``n_routed_experts`` outputs; 0 holds them all.
+    n_held: int = 0
+    first_held: int = 0
+
+    def __post_init__(self):
+        if not 0 <= self.first_held < self.first_held + self.held_count \
+                <= self.n_routed_experts:
+            raise ValueError(
+                f"held experts {self.first_held}..{self.first_held + self.held_count - 1}"
+                f" outside the {self.n_routed_experts} routed experts")
 
     @property
     def effective_dense_d_ff(self) -> int:
         return self.dense_d_ff or self.moe_d_ff
 
+    @property
+    def held_count(self) -> int:
+        return self.n_held or self.n_routed_experts
+
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V2/V3)."""
+    """Multi-head Latent Attention (DeepSeek-V2/V3).  ``q_lora_rank=None``
+    projects queries directly (``wq``), without the low-rank path."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -109,6 +125,7 @@ class ModelConfig:
 
     # --- attention details -------------------------------------------------
     rope_theta: float = 10_000.0
+    rope_interleave: bool = False     # rotate (x[2i], x[2i+1]) pairs (HF DeepSeek-V3)
     attn_window: int = 0              # 0 -> full attention
     attn_logit_softcap: float = 0.0   # gemma-2 style softcap (0 = off)
     qkv_bias: bool = False
@@ -220,11 +237,14 @@ def reduced_for_smoke(cfg: ModelConfig) -> ModelConfig:
             moe_d_ff=128,
             first_k_dense=min(cfg.moe.first_k_dense, 1),
             dense_d_ff=256 if cfg.moe.first_k_dense else 0,
-            capacity_factor=8.0,      # effectively dropless at smoke scale
+            # a chip's share stays a share: half of the four experts
+            n_held=2 if cfg.moe.n_held else 0,
+            first_held=0,
         )
     if cfg.mla is not None:
         kw["mla"] = MLAConfig(
-            q_lora_rank=64, kv_lora_rank=32,
+            q_lora_rank=None if cfg.mla.q_lora_rank is None else 64,
+            kv_lora_rank=32,
             qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
         )
     if cfg.ssm is not None:
@@ -242,3 +262,32 @@ def reduced_for_smoke(cfg: ModelConfig) -> ModelConfig:
     if cfg.mtp_depth:
         kw["mtp_depth"] = 1
     return cfg.replace(**kw)
+
+
+def deepseek_v3_keys(cfg: ModelConfig) -> dict:
+    """An MLA + MoE config in the Hugging Face ``DeepseekV3`` config keys
+    (the ones that fix its shapes), with the chip's ``expert_share``:
+    ``n_routed_experts`` counts the experts held here, ``expert_share``
+    the router's outputs and the first held expert."""
+    m, a = cfg.moe, cfg.mla
+    return {
+        "hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layers,
+        "first_k_dense_replace": m.first_k_dense,
+        "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "q_lora_rank": a.q_lora_rank, "kv_lora_rank": a.kv_lora_rank,
+        "qk_nope_head_dim": a.qk_nope_head_dim,
+        "qk_rope_head_dim": a.qk_rope_head_dim,
+        "qk_head_dim": a.qk_head_dim, "v_head_dim": a.v_head_dim,
+        "intermediate_size": m.effective_dense_d_ff,
+        "moe_intermediate_size": m.moe_d_ff,
+        "n_routed_experts": m.held_count, "num_experts_per_tok": m.top_k,
+        "n_shared_experts": m.n_shared_experts,
+        "routed_scaling_factor": m.routed_scaling,
+        "scoring_func": m.score_func, "vocab_size": cfg.vocab_size,
+        "rope_theta": cfg.rope_theta, "rope_interleave": cfg.rope_interleave,
+        "rms_norm_eps": cfg.norm_eps, "hidden_act": cfg.mlp_activation,
+        "tie_word_embeddings": cfg.tie_embeddings,
+        "expert_share": {"router_experts": m.n_routed_experts,
+                         "first_held": m.first_held},
+    }

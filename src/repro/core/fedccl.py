@@ -212,6 +212,13 @@ class FedCCL:
 
     # ----------------------------------------------------------------- setup
     def setup(self, specs: list[ClientSpec]) -> dict[str, list[str]]:
+        """Registers the first clients, each local model seeded with the
+        initial parameters, which the facade then lets go: a large model's
+        initial copy is freed once every tier has moved on from it.  Runs
+        once; later clients come in through ``join``."""
+        if self._init_params is None:
+            raise RuntimeError("setup runs once; later clients join through "
+                               "join()")
         assignments = self.pe.bootstrap(specs)
         for i, spec in enumerate(specs):
             c = Client(spec=spec,
@@ -223,6 +230,7 @@ class FedCCL:
             c.local_params = self._init_params
             self.clients.append(c)
             self._clients_by_id[spec.client_id] = c
+        self._init_params = None
         return assignments
 
     # ------------------------------------------------------------------- run

@@ -119,9 +119,16 @@ class AsyncSimRuntime:
             elif ev.kind == "submit":
                 with telemetry_scope(tel), maybe_span(tel, "client.update",
                                                       args=args):
-                    for level, key, p, m in ev.payload:
+                    jobs = ev.payload
+                    while jobs:
+                        # a job leaves the event as it starts, and its
+                        # snapshot and update go with the loop's names once
+                        # submitted: a large model holds no superseded copy
+                        # through the event's later trainings
+                        level, key, p, m = jobs.pop(0)
                         new_p, new_meta, delta = client.train_update(
                             p, m, self.store.model_key(level, key))
+                        del p
                         # staleness vs the round at enqueue time: queued-but-
                         # undrained updates count (in batched mode the
                         # materialized meta lags the logical server round)
@@ -132,6 +139,7 @@ class AsyncSimRuntime:
                         if batched and (self.store.pending_depth(level, key)
                                         >= self.store.max_coalesce):
                             self.store.drain(level, key)
+                        del new_p
                 self.completed_rounds[client.spec.client_id] += 1
                 if self.completed_rounds[client.spec.client_id] < target:
                     self._push(self.now + 1e-3, "round_start", ev.client_idx)

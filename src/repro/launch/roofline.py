@@ -162,7 +162,8 @@ def _attn_flops_per_token(cfg, ctx: float, *, decode: bool = False,
     d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     if cfg.mla is not None:
         m = cfg.mla
-        q_proj = 2 * d * m.q_lora_rank + 2 * m.q_lora_rank * h * m.qk_head_dim
+        q_proj = (2 * d * h * m.qk_head_dim if m.q_lora_rank is None else
+                  2 * d * m.q_lora_rank + 2 * m.q_lora_rank * h * m.qk_head_dim)
         kv_proj = 2 * d * (m.kv_lora_rank + m.qk_rope_head_dim)
         o_proj = 2 * h * m.v_head_dim * d
         if decode and not mla_absorb:
@@ -248,7 +249,10 @@ def forward_flops_per_token(cfg, ctx: float, *, decode: bool = False,
         if cfg.is_moe and layer >= cfg.moe.first_k_dense:
             m = cfg.moe
             total += 2 * cfg.d_model * m.n_routed_experts
-            total += (m.top_k * m.capacity_factor + m.n_shared_experts) * \
+            # dropless: each token's top-k assignments, of which the held
+            # share lands here, plus the shared experts
+            total += (m.top_k * m.held_count / m.n_routed_experts
+                      + m.n_shared_experts) * \
                 _mlp_flops_per_token(cfg.d_model, m.moe_d_ff)
         elif cfg.is_moe:
             total += _mlp_flops_per_token(cfg.d_model, m0_ff(cfg))
@@ -322,9 +326,6 @@ def analytic_costs(cfg, shape, n_chips: int, mesh_shape: dict, *,
         fwd = forward_flops_per_token(cfg, ctx, decode=True,
                                       window=eff_window,
                                       mla_absorb=mla_absorb) + 2 * d * V
-        if cfg.is_moe:
-            # decode routes real top-k only (capacity ~= top_k at B tokens)
-            pass
         flops_global = fwd * B
         flops_dev = flops_global / n_chips
 

@@ -7,6 +7,7 @@ peak memory stays linear in sequence length.
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -41,10 +42,16 @@ def attention_schema(cfg: ModelConfig) -> dict:
 def mla_schema(cfg: ModelConfig) -> dict:
     m = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
+    if m.q_lora_rank is None:
+        query = {"wq": ParamSpec((d, h, m.qk_head_dim), ("embed", "heads", "head_dim"))}
+    else:
+        query = {
+            "wq_a": ParamSpec((d, m.q_lora_rank), ("embed", "rank")),
+            "q_norm": ParamSpec((m.q_lora_rank,), ("rank",), init="ones", dtype="float32"),
+            "wq_b": ParamSpec((m.q_lora_rank, h, m.qk_head_dim), ("rank", "heads", "head_dim")),
+        }
     return {
-        "wq_a": ParamSpec((d, m.q_lora_rank), ("embed", "rank")),
-        "q_norm": ParamSpec((m.q_lora_rank,), ("rank",), init="ones", dtype="float32"),
-        "wq_b": ParamSpec((m.q_lora_rank, h, m.qk_head_dim), ("rank", "heads", "head_dim")),
+        **query,
         "wkv_a": ParamSpec((d, m.kv_lora_rank), ("embed", "rank")),
         "kv_norm": ParamSpec((m.kv_lora_rank,), ("rank",), init="ones", dtype="float32"),
         "wk_rope": ParamSpec((d, m.qk_rope_head_dim), ("embed", "head_dim")),
@@ -157,6 +164,130 @@ def flash_attention(q, k, v, *, causal: bool, window: int, scale: float,
     _, outs = jax.lax.scan(q_step, None, jnp.arange(nq))    # (nq,b,blkq,kv,g,hd_v)
     out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, s + pad_q, kvh, g, hd_v)
     return out[:, :s].astype(v.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Causal flash attention with its own backward pass (training at long s)
+# ---------------------------------------------------------------------------
+
+
+#: queries (and keys) per block of ``causal_flash_attention``
+FLASH_BLOCK = 512
+
+
+def causal_flash_attention(q, k, v, scale: float):
+    """Causal attention over blocks, with a hand-written backward pass.
+
+    q, k: (b, s, h, dk); v: (b, s, h, dv) -> (b, s, h, dv).  The forward is
+    the online softmax over the key blocks at or before each query block;
+    it keeps the output and each row's log-sum-exp, and the backward
+    recomputes each block's probabilities from them (FlashAttention-2), so
+    neither pass holds more than a few (block, block) tiles per head and
+    blocks above the diagonal are never computed.  Products take the
+    inputs' dtype with float32 accumulation; the softmax is float32.
+    """
+    s = q.shape[1]
+    blk = min(FLASH_BLOCK, s)
+    pad = (-s) % blk
+    if pad:
+        # padded keys lie after every real query: the causal mask hides them
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+    out = _causal_flash(q, k, v, float(scale), blk)
+    return out[:, :s]
+
+
+def _blocks(x, blk):
+    """(b, s, h, d) -> (n, b, h, blk, d): the sequence's blocks, head-major."""
+    b, s, h, d = x.shape
+    return x.reshape(b, s // blk, blk, h, d).transpose(1, 0, 3, 2, 4)
+
+
+def _unblocks(x):
+    """(n, b, h, blk, d) -> (b, n * blk, h, d)."""
+    n, b, h, blk, d = x.shape
+    return x.transpose(1, 0, 3, 2, 4).reshape(b, n * blk, h, d)
+
+
+def _masked_scores(qi, kj, i, j, scale, blk):
+    sc = jnp.einsum("bhqd,bhkd->bhqk", qi, kj,
+                    preferred_element_type=jnp.float32) * scale
+    q_pos = i * blk + jnp.arange(blk)
+    k_pos = j * blk + jnp.arange(blk)
+    return jnp.where(k_pos[None, :] <= q_pos[:, None], sc, NEG_INF)
+
+
+def _causal_flash_fwd(q, k, v, scale, blk):
+    qb, kb, vb = _blocks(q, blk), _blocks(k, blk), _blocks(v, blk)
+    _, b, h, _, dv = vb.shape
+
+    def q_block(_, i):
+        qi = qb[i]
+
+        def kv_step(j, carry):
+            m, l, acc = carry
+            sc = _masked_scores(qi, kb[j], i, j, scale, blk)
+            m_new = jnp.maximum(m, sc.max(-1))
+            p = jnp.exp(sc - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(vb.dtype), vb[j],
+                preferred_element_type=jnp.float32)
+            return m_new, l * corr + p.sum(-1), acc
+
+        m0 = jnp.full((b, h, blk), NEG_INF, jnp.float32)
+        init = (m0, jnp.zeros_like(m0), jnp.zeros((b, h, blk, dv), jnp.float32))
+        m, l, acc = jax.lax.fori_loop(0, i + 1, kv_step, init)
+        return None, ((acc / l[..., None]).astype(v.dtype), m + jnp.log(l))
+
+    _, (ob, lse) = jax.lax.scan(q_block, None, jnp.arange(qb.shape[0]))
+    return _unblocks(ob), (q, k, v, ob, lse)
+
+
+def _causal_flash_bwd(scale, blk, res, dout):
+    q, k, v, ob, lse = res
+    qb, kb, vb, dob = (_blocks(a, blk) for a in (q, k, v, dout))
+    # D_i = rowsum(dO_i * O_i), the softmax backward's correction
+    delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32), -1)
+
+    def q_block(carry, i):
+        qi, doi = qb[i], dob[i]
+
+        def kv_step(j, c):
+            dq, dk, dv = c
+            kj, vj = kb[j], vb[j]
+            p = jnp.exp(_masked_scores(qi, kj, i, j, scale, blk)
+                        - lse[i][..., None])
+            dp = jnp.einsum("bhqd,bhkd->bhqk", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = p * (dp - delta[i][..., None])
+            dv = dv.at[j].add(jnp.einsum(
+                "bhqk,bhqd->bhkd", p.astype(doi.dtype), doi,
+                preferred_element_type=jnp.float32))
+            dk = dk.at[j].add(scale * jnp.einsum(
+                "bhqk,bhqd->bhkd", ds.astype(qi.dtype), qi,
+                preferred_element_type=jnp.float32))
+            dq = dq + scale * jnp.einsum("bhqk,bhkd->bhqd", ds.astype(kj.dtype),
+                                         kj, preferred_element_type=jnp.float32)
+            return dq, dk, dv
+
+        dk, dv = carry
+        dq0 = jnp.zeros(qi.shape, jnp.float32)
+        dq, dk, dv = jax.lax.fori_loop(0, i + 1, kv_step, (dq0, dk, dv))
+        return (dk, dv), dq.astype(q.dtype)
+
+    init = (jnp.zeros(kb.shape, jnp.float32), jnp.zeros(vb.shape, jnp.float32))
+    (dk, dv), dqb = jax.lax.scan(q_block, init, jnp.arange(qb.shape[0]))
+    return (_unblocks(dqb), _unblocks(dk).astype(k.dtype),
+            _unblocks(dv).astype(v.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _causal_flash(q, k, v, scale, blk):
+    return _causal_flash_fwd(q, k, v, scale, blk)[0]
+
+
+_causal_flash.defvjp(_causal_flash_fwd, _causal_flash_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -298,14 +429,18 @@ def mla_forward(cfg: ModelConfig, p: dict, x, *, positions, window: int,
     h = cfg.n_heads
     scale = m.qk_head_dim ** -0.5
 
-    cq = _rms(jnp.einsum("bsd,dr->bsr", x, p["wq_a"]), p["q_norm"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])                 # (b,s,h,qk_head_dim)
+    eps = cfg.norm_eps
+    if m.q_lora_rank is None:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    else:
+        cq = _rms(jnp.einsum("bsd,dr->bsr", x, p["wq_a"]), p["q_norm"], eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])             # (b,s,h,qk_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_interleave)
 
-    c_kv = _rms(jnp.einsum("bsd,dr->bsr", x, p["wkv_a"]), p["kv_norm"])  # (b,s,rank)
+    c_kv = _rms(jnp.einsum("bsd,dr->bsr", x, p["wkv_a"]), p["kv_norm"], eps)  # (b,s,rank)
     k_rope_new = apply_rope(jnp.einsum("bsd,dk->bsk", x, p["wk_rope"]), positions,
-                            cfg.rope_theta)                        # (b,s,rope_dim)
+                            cfg.rope_theta, cfg.rope_interleave)  # (b,s,rope_dim)
 
     if cache is not None:
         # cache_pos: scalar or (b,) per-sequence offsets (continuous batching)
@@ -344,9 +479,12 @@ def mla_forward(cfg: ModelConfig, p: dict, x, *, positions, window: int,
                                       (*k_nope.shape[:2], h, m.qk_rope_head_dim)).astype(k_nope.dtype)],
             axis=-1)
         q_full = jnp.concatenate([q_nope, q_rope], axis=-1)      # (b,s,h,qk_hd)
-        qg = q_full.reshape(b, s, h, 1, m.qk_head_dim)
-        out = flash_attention(qg, k_full, v_exp, causal=causal, window=window,
-                              scale=scale).reshape(b, s, h, m.v_head_dim)
+        if causal and not window:
+            out = causal_flash_attention(q_full, k_full, v_exp, scale)
+        else:
+            qg = q_full.reshape(b, s, h, 1, m.qk_head_dim)
+            out = flash_attention(qg, k_full, v_exp, causal=causal, window=window,
+                                  scale=scale).reshape(b, s, h, m.v_head_dim)
         y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), p["wo"])
         return constrain(y, ("batch", "seq", "embed"), rules), None
 

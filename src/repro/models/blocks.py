@@ -27,7 +27,7 @@ from repro.models.attention import (
 )
 from repro.models.layers import rmsnorm, rmsnorm_schema
 from repro.models.mlp import mlp_forward, mlp_schema
-from repro.models.moe import moe_forward, moe_schema
+from repro.models.moe import moe_forward, moe_schema, zero_aux
 from repro.models.rglru import init_rglru_state, rglru_forward, rglru_schema
 from repro.models.ssm import init_ssm_state, ssm_forward, ssm_schema
 
@@ -70,10 +70,12 @@ def _attn_apply(cfg, p, x, *, positions, window, causal, rules, cache, cache_pos
 def block_apply(cfg: ModelConfig, kind: str, p: dict, h, *, positions,
                 rules=None, cache=None, cache_pos=None, window_override=None,
                 mla_absorb: bool = True):
-    """Returns (h_out, new_cache, aux_loss)."""
+    """Returns (h_out, new_cache, aux): ``aux`` as ``moe.zero_aux`` lays it
+    out, the load-balance loss and, in an MoE model, the held experts'
+    assignment counts."""
     eps = cfg.norm_eps
     causal = not cfg.encoder_only
-    zero = jnp.zeros((), jnp.float32)
+    zero = zero_aux(cfg)
 
     if kind == "ssm":
         y, new_state = ssm_forward(cfg, p["mixer"], rmsnorm(p["norm"], h, eps),

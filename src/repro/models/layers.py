@@ -37,11 +37,18 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
+def apply_rope(x, positions, theta: float, interleave: bool = False):
     """x: (b, seq, heads, head_dim) or (b, seq, head_dim);
     positions: (seq,) shared, or (b, seq) per-sequence (continuous
-    batching: each request at its own decode offset)."""
+    batching: each request at its own decode offset).
+
+    Rotates the pairs ``(x[i], x[i + hd/2])``; ``interleave=True`` rotates
+    ``(x[2i], x[2i+1])`` instead and returns them de-interleaved (evens,
+    then odds), as Hugging Face's DeepSeek-V3 does: queries and keys get
+    the same layout, so their products are unchanged."""
     head_dim = x.shape[-1]
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     freqs = rope_freqs(head_dim, theta)                        # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs
     if x.ndim == 4:                                            # heads axis present

@@ -24,6 +24,7 @@ from repro.models.blocks import (
     stack_layout,
 )
 from repro.models.layers import rmsnorm, rmsnorm_schema
+from repro.models.moe import zero_aux
 from repro.sharding.logical import (
     ParamSpec,
     Rules,
@@ -107,25 +108,28 @@ class LanguageModel:
     def forward(self, params, *, tokens=None, embeds=None, mask=None,
                 rules: Rules | None = None, window_override=None,
                 mla_absorb: bool = True):
-        """Full-sequence forward.  Returns (logits, aux)."""
+        """Full-sequence forward.  Returns (logits, aux): ``aux`` holds
+        ``moe_loss``, the final ``hidden`` states and, in an MoE model,
+        ``moe_load``, the (token, k) assignments routed to each held expert
+        summed over the layers."""
         cfg = self.cfg
         h = self._embed_inputs(params, tokens, embeds, mask, rules)
         s = h.shape[1]
         positions = jnp.arange(s)
-        moe_loss = jnp.zeros((), jnp.float32)
+        aux = zero_aux(cfg)
 
         for si, (mode, kinds, _repeat) in enumerate(self.layout):
             seg_params = params["segments"][f"seg{si}"]
             if mode == "scan":
                 def body(carry, xs, kinds=kinds):
-                    hh, aux = carry
+                    hh, acc = carry
                     for i, kind in enumerate(kinds):
                         hh, _, a = block_apply(
                             cfg, kind, xs[f"b{i}"], hh, positions=positions,
                             rules=rules, window_override=window_override,
                             mla_absorb=mla_absorb)
-                        aux = aux + a
-                    return (hh, aux), None
+                        acc = jax.tree.map(jnp.add, acc, a)
+                    return (hh, acc), None
 
                 if cfg.remat == "full":
                     body = jax.checkpoint(body, prevent_cse=False)
@@ -133,20 +137,24 @@ class LanguageModel:
                     body = jax.checkpoint(
                         body, policy=jax.checkpoint_policies.dots_saveable,
                         prevent_cse=False)
-                (h, moe_loss), _ = jax.lax.scan(body, (h, moe_loss), seg_params)
+                (h, aux), _ = jax.lax.scan(body, (h, aux), seg_params)
             else:
                 for i, kind in enumerate(kinds):
                     h, _, a = block_apply(
                         cfg, kind, seg_params[f"b{i}"], h, positions=positions,
                         rules=rules, window_override=window_override,
                         mla_absorb=mla_absorb)
-                    moe_loss = moe_loss + a
+                    aux = jax.tree.map(jnp.add, aux, a)
 
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-        logits = self._head(params, h, rules)
-        return logits, {"moe_loss": moe_loss, "hidden": h}
+        logits = self.head(params, h, rules)
+        out = {"moe_loss": aux["loss"], "hidden": h}
+        if "load" in aux:
+            out["moe_load"] = aux["load"]
+        return logits, out
 
-    def _head(self, params, h, rules):
+    def head(self, params, h, rules=None):
+        """Logits of final hidden states (after ``final_norm``)."""
         if self.cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
         else:
@@ -168,7 +176,7 @@ class LanguageModel:
         h, _, _ = block_apply(cfg, kind, p["block"], h, positions=positions,
                               rules=rules)
         h = rmsnorm(p["final_norm"], h, cfg.norm_eps)
-        return self._head(params, h, rules)
+        return self.head(params, h, rules)
 
     # ------------------------------------------------------------- decode
     def init_caches(self, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -228,7 +236,7 @@ class LanguageModel:
             new_caches[f"seg{si}"] = new_seg
 
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-        return self._head(params, h, rules), new_caches
+        return self.head(params, h, rules), new_caches
 
 
 def build_model(cfg: ModelConfig) -> LanguageModel:

@@ -2,7 +2,9 @@
 
 Counts are derived from the *schema*, so they are exact by construction;
 ``active_only`` subtracts non-activated routed experts (MoE) for the
-6*N_active*D convention.
+6*N_active*D convention.  A config that holds a share of the routed experts
+(``MoEConfig.n_held``) counts that share: its schema holds only those, and
+a token activates ``top_k * n_held / n_routed_experts`` of them on average.
 """
 
 from __future__ import annotations
@@ -37,6 +39,7 @@ def count_params_analytic(cfg, active_only: bool = False,
         # each routed expert: 3 matrices d x moe_ff
         per_expert = 3 * cfg.d_model * m.moe_d_ff
         n_moe_layers = cfg.n_layers - m.first_k_dense
-        inactive = (m.n_routed_experts - m.top_k) * per_expert * n_moe_layers
-        total -= inactive
+        held = m.held_count
+        active = m.top_k * held / m.n_routed_experts
+        total -= round((held - active) * per_expert * n_moe_layers)
     return total

@@ -189,6 +189,7 @@ class MetricsRegistry:
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, LogHistogram] = {}
+        self._deferred: dict = {}
 
     def _get(self, table: dict, name: str, factory):
         inst = table.get(name)
@@ -206,10 +207,24 @@ class MetricsRegistry:
     def histogram(self, name: str) -> LogHistogram:
         return self._get(self._histograms, name, LogHistogram)
 
+    def defer(self, name: str, read) -> None:
+        """Counters read only when the registry is dumped: ``read()``
+        returns ``{counter: value}``, added to what those counters hold.
+        For counts kept where reading them costs (on an accelerator): the
+        recorder pays one read per dump, not one per update.  A second
+        ``defer`` under the same ``name`` replaces the first."""
+        with self._lock:
+            self._deferred[name] = read
+
     def dump(self) -> dict:
+        counters = {n: c.value for n, c in self._counters.items()}
+        with self._lock:
+            deferred = list(self._deferred.values())
+        for read in deferred:
+            for n, v in read().items():
+                counters[n] = counters.get(n, 0) + int(v)
         return {
-            "counters": {n: c.value
-                         for n, c in sorted(self._counters.items())},
+            "counters": dict(sorted(counters.items())),
             "gauges": {n: g.value for n, g in sorted(self._gauges.items())},
             "histograms": {n: h.snapshot()
                            for n, h in sorted(self._histograms.items())},

@@ -30,6 +30,8 @@ EXPECTED = {
                     d_ff=13696, vocab_size=151552),
     "deepseek-7b": dict(n_layers=30, d_model=4096, n_heads=32, n_kv_heads=32,
                         d_ff=11008, vocab_size=102400),
+    "kanana2-30b-a3b": dict(n_layers=48, d_model=2048, n_heads=32,
+                            n_kv_heads=32, d_ff=768, vocab_size=128256),
 }
 
 
@@ -42,7 +44,7 @@ def test_exact_assigned_numbers(arch):
 
 
 def test_all_ten_archs_present():
-    assert len(ALL_ARCHS) == 10
+    assert len(ALL_ARCHS) == 11
     families = {get_config(a).family for a in ALL_ARCHS}
     assert families == {"dense", "moe", "ssm", "hybrid", "audio", "vlm"}
 

@@ -48,6 +48,72 @@ def test_agg_pytrees_dtype(dtype, rng):
                                    np.asarray(b, np.float32), atol=2e-2)
 
 
+def _lstm_like_tree(rng, dtype):
+    """The solar forecaster's leaf shapes: 141,953 parameters."""
+    shapes = {"encoder": {"wx": (10, 512), "wh": (128, 512), "b": (512,)},
+              "decoder": {"wx": (9, 512), "wh": (128, 512), "b": (512,)},
+              "head_w": (128, 1), "head_b": (1,)}
+    return jax.tree.map(lambda s: jnp.asarray(rng.standard_normal(s), dtype),
+                        shapes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def _whole_route(trees, w):
+    """The fold as one flattened call: every leaf raveled to float32,
+    stacked, one kernel pass, cut back into the leaves."""
+    from repro.utils.tree import unflatten_params
+
+    flats = [jnp.concatenate([jnp.ravel(x).astype(jnp.float32)
+                              for x in jax.tree.leaves(t)]) for t in trees]
+    return unflatten_params(aggregate_flat(jnp.stack(flats), w), trees[0])
+
+
+def _assert_same_bits(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        np.testing.assert_array_equal(np.asarray(x).view(np.uint8),
+                                      np.asarray(y).view(np.uint8))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("chunk", [8192, 50_000, 1 << 24],
+                         ids=["tile-chunks", "ragged-chunks", "whole"])
+def test_agg_pytrees_chunked_same_bits(dtype, chunk, rng, monkeypatch):
+    """Folding the LSTM tree chunk by chunk (leaves split across chunks)
+    gives the same bits as the whole tree flattened into one call; at a
+    chunk wider than the tree, as the default is, it is one chunk."""
+    import repro.kernels.fedavg_agg.ops as ops
+
+    monkeypatch.setattr(ops, "CHUNK", chunk)
+    trees = [_lstm_like_tree(rng, dtype) for _ in range(2)]
+    w = [0.37, 0.63]
+    _assert_same_bits(ops.aggregate_pytrees(trees, w), _whole_route(trees, w))
+
+
+def test_agg_pytrees_chunk_bounds_the_transient(monkeypatch, rng):
+    """A tree of several chunks reaches the kernel one chunk at a time:
+    no stacked buffer is wider than the chunk, whatever the tree."""
+    import repro.kernels.fedavg_agg.ops as ops
+
+    widths = []
+    real = ops.aggregate_flat
+
+    def spy(stacked, weights, **kw):
+        widths.append(stacked.shape)
+        return real(stacked, weights, **kw)
+
+    monkeypatch.setattr(ops, "aggregate_flat", spy)
+    monkeypatch.setattr(ops, "CHUNK", 8192)
+    trees = [{"big": jnp.asarray(rng.standard_normal((300, 170)), jnp.bfloat16),
+              "small": jnp.asarray(rng.standard_normal(9), jnp.float32),
+              "empty": jnp.zeros((0, 4), jnp.float32)}
+             for _ in range(3)]
+    out = ops.aggregate_pytrees(trees, [0.2, 0.3, 0.5])
+    assert len(widths) == -(-(300 * 170 + 9) // 8192)
+    assert all(n == 3 and t <= 8192 for n, t in widths)
+    assert sum(t for _, t in widths) == 300 * 170 + 9
+    _assert_same_bits(out, _whole_route(trees, [0.2, 0.3, 0.5]))
+
+
 @settings(max_examples=15, deadline=None)
 @given(n=st.integers(2, 6), t=st.integers(1, 3000))
 def test_agg_kernel_property(n, t):

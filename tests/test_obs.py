@@ -710,3 +710,27 @@ def test_every_in_process_queue_stamps_its_updates(monkeypatch, store_cls,
     assert (t0, args["n"], sorted(args["waits"])) == (1_000, 3,
                                                       [700, 700, 900])
     assert tel.metrics.histogram("queue_wait_ns").snapshot()["count"] == 3
+
+
+def test_deferred_counters_are_read_once_per_dump():
+    """``MetricsRegistry.defer``: counts kept elsewhere (on a device) join
+    the dump's counters, added to what a counter of the same name holds,
+    read at each dump and never in between."""
+    from repro.obs.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    reads = []
+    held = {"x.0": 3, "x.1": 4}
+
+    def read():
+        reads.append(1)
+        return dict(held)
+
+    reg.defer("x", read)
+    reg.counter("x.0").inc(10)
+    assert reads == []
+    assert reg.dump()["counters"] == {"x.0": 13, "x.1": 4}
+    held["x.1"] = 9
+    assert reg.dump()["counters"]["x.1"] == 9 and len(reads) == 2
+    reg.defer("x", lambda: {"x.1": 1})          # replaces the first
+    assert reg.dump()["counters"] == {"x.0": 10, "x.1": 1}

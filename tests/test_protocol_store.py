@@ -92,6 +92,74 @@ def test_sim_staleness_occurs():
     assert 0 < stats["fast_path_frac"] < 1
 
 
+def test_sim_runtime_frees_a_submitted_job(monkeypatch):
+    """A submit event holds a job's fetched snapshot and trained update only
+    until the update is submitted: at a client's global training (the last
+    of its event) every model an earlier training was given or made is
+    dead, or still held by the store, a client's local tier or a job not
+    yet trained."""
+    import weakref
+
+    from repro.core import fedccl as fedccl_mod
+    from repro.core.runtime_sim import AsyncSimRuntime
+
+    runtimes = []
+
+    class Recording(AsyncSimRuntime):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            runtimes.append(self)
+
+    monkeypatch.setattr(fedccl_mod, "AsyncSimRuntime", Recording)
+    made, calls, extra, checked = [], {}, [], [0]
+    fed = None
+
+    def train_fn(params, dataset, rng, anchor):
+        k = calls[id(dataset)] = calls.get(id(dataset), 0) + 1
+        if k % 3 == 0:          # local, cluster, global: the global job
+            store = fed.store
+            held = [store.params("global")["w"]]
+            held += [store.params("cluster", c)["w"] for c in store.keys()]
+            held += [c.local_params["w"] for c in fed.clients]
+            held += [job[2]["w"] for ev in runtimes[-1]._heap
+                     for job in (ev.payload or ())]
+            held.append(params["w"])
+            alive = [r() for r in made if r() is not None]
+            extra.append(sum(not any(a is h for h in held) for a in alive))
+            checked[0] += 1
+        out = {"w": params["w"] + 1.0}
+        made.extend([weakref.ref(params["w"]), weakref.ref(out["w"])])
+        return out, 10, 1
+
+    cfg = FedCCLConfig(
+        spaces=(ClusterSpaceConfig("loc", eps=100.0, min_samples=2,
+                                   metric="haversine"),),
+        ewc_lambda=0.05, runtime="sim", seed=3)
+    fed = FedCCL(cfg, {"w": jnp.zeros(())}, train_fn)
+    fed.setup([ClientSpec(f"s{i}", {"loc": np.array([48.2 + 0.01 * i,
+                                                     16.4])},
+                          (i,), speed=1.0 + 0.3 * i) for i in range(4)])
+    fed.run(rounds=3)
+    assert checked[0] == 12
+    assert extra == [0] * 12
+
+
+def test_setup_lets_the_initial_parameters_go():
+    """setup seeds every local model with the initial parameters and keeps
+    no reference of its own: once each tier has moved on they are freed.
+    It runs once; a second call says how clients come in later."""
+    import weakref
+
+    fed = make_fed()
+    init = weakref.ref(fed.clients[0].local_params["w"])
+    assert init() is not None
+    fed.run(rounds=1)
+    assert init() is None
+    with pytest.raises(RuntimeError, match="join"):
+        fed.setup([ClientSpec("late", {"loc": np.array([48.2, 16.4])},
+                              (1.0, 10))])
+
+
 def test_dropout_resilience():
     cfg = FedCCLConfig(
         spaces=(ClusterSpaceConfig("loc", eps=100.0, min_samples=2,

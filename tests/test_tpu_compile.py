@@ -116,3 +116,40 @@ def test_solar_sgd_step_compiles_at_full_width(shape):
     assert n_params == 141_953
     # the step must fit one 16 GB chip with room for the federation's models
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_held_expert_layer_and_causal_attention_compile_at_published_widths(shape):
+    """Kanana-2's MoE layer over 8 held of 128 experts (the grouped
+    matmuls lower to the chip's ragged-dot kernel) and its 32-head causal
+    attention at 8,192 tokens, forward and backward, in bfloat16."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.attention import causal_flash_attention
+    from repro.models.moe import moe_forward, moe_schema
+    from repro.sharding.logical import schema_shapes
+
+    cfg = get_config("kanana2-30b-a3b")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, n_held=8))
+    bf16 = jnp.bfloat16
+    params = jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                          schema_shapes(moe_schema(cfg), bf16))
+    x = shape((1, 8192, cfg.d_model), bf16)
+
+    def moe_loss(p, x):
+        y, aux = moe_forward(cfg, p, x)
+        return jnp.sum(y.astype(jnp.float32)), aux["load"]
+
+    compiled = jax.jit(jax.grad(moe_loss, has_aux=True)).lower(
+        params, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+    q = shape((1, 8192, 32, 192), bf16)
+    v = shape((1, 8192, 32, 128), bf16)
+
+    def attn_loss(q, k, v):
+        return jnp.sum(causal_flash_attention(q, k, v, 192 ** -0.5)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(attn_loss, (0, 1, 2))).lower(q, q, v).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
