@@ -46,8 +46,11 @@ from repro.obs.record import (
     telemetry_scope,
     trace_scope,
 )
-from repro.privacy.secure_agg import PairwiseMasker
+from repro.privacy.secure_agg import PAIRS_PER_CALL, PairwiseMasker
 from repro.training.fed_solar import make_train_fn
+
+# one block of pair seed rows: eight uint32 words for each of its pairs
+SEED_BLOCK_BYTES = PAIRS_PER_CALL * 8 * 4
 
 # =========================================================================
 # metrics: log-bucketed histograms
@@ -559,14 +562,51 @@ def test_secure_runtime_spans_rounds_models_and_privacy():
     assert _within(events, "secure_fold", "secure.model")
     assert _within(events, "mask", "secure.model")
     assert _within(events, "secure.model", "secure.round")
-    # each mask and each recovery uploads one flat model of 4 floats, and
+    # each mask and each recovery uploads one block of pair seed rows, and
     # each of the 10-window trainings its batches
     per_window = sum(fed.clients[0].spec.dataset[k][0].nbytes
                      for k in ("history", "forecast", "target"))
     m = fed.store.telemetry.metrics.dump()["counters"]
     trained = m["windows_trained"]
-    assert m["h2d_bytes"] == trained * per_window + 16 * (
+    assert m["h2d_bytes"] == trained * per_window + SEED_BLOCK_BYTES * (
         names.count("mask") + names.count("reconstruct"))
+    # a mask draws a pair per other expected member of its model, a
+    # recovery one per survivor and dropped member
+    members = {fed.store.model_key("global"): len(fed.clients)}
+    for c in fed.clients:
+        for k in c.cluster_keys:
+            mk = fed.store.model_key("cluster", k)
+            members[mk] = members.get(mk, 0) + 1
+    assert m["mask_pairs"] == sum(
+        members[e[5]["key"]] - 1 for e in events if e[2] == "mask") + sum(
+        e[5]["missing"] * (members[e[5]["key"]] - e[5]["missing"])
+        for e in events if e[2] == "reconstruct")
+
+
+@pytest.mark.parametrize("members,dropped,blocks", [
+    (5, 2, 1),       # 4 mask pairs, 3 x 2 recovery pairs
+    (40, 10, 2),     # 39 mask pairs, 30 x 10 = 300 recovery pairs
+])
+def test_masks_count_pairs_and_upload_only_seed_rows(members, dropped,
+                                                     blocks):
+    """A mask and a recovery upload their pairs' seed rows, one block of
+    ``PAIRS_PER_CALL`` pairs a transfer, and count the pairs they draw; no
+    mask crosses from the host."""
+    masker = PairwiseMasker(seed=4, mask_scale=1.0)
+    ids = [f"c{i:02d}" for i in range(members)]
+    delta = jnp.ones(4_001, jnp.float32)
+    tel = Telemetry()
+    with telemetry_scope(tel):
+        masker.mask_delta_flat(delta, ids[0], ids, 0, "k", weight=3.0)
+        m = tel.metrics.dump()["counters"]
+        assert m["mask_pairs"] == members - 1
+        assert m["h2d_bytes"] == SEED_BLOCK_BYTES
+        assert m["h2d_transfers"] == 1
+        masker.reconstruct({"w": delta}, ids[:dropped], ids[dropped:], 0, "k")
+    m = tel.metrics.dump()["counters"]
+    assert m["mask_pairs"] == members - 1 + dropped * (members - dropped)
+    assert m["h2d_bytes"] == SEED_BLOCK_BYTES * (1 + blocks)
+    assert m["h2d_transfers"] == 1 + blocks
 
 
 def test_join_and_serving_spans():

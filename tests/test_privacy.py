@@ -19,7 +19,8 @@ from repro.core.aggregation import (
 from repro.core.store import ModelStore
 from repro.privacy.accountant import RDPAccountant, rdp_to_epsilon
 from repro.privacy.dp import DPConfig, DPPrivatizer
-from repro.privacy.secure_agg import PairwiseMasker
+from repro.privacy import secure_agg
+from repro.privacy.secure_agg import PairwiseMasker, _pair_masks
 from repro.utils.tree import flatten_params, unflatten_params
 
 from test_batched_aggregation import make_fed, tree_of
@@ -140,6 +141,100 @@ def test_dropout_reconstruction_cancels_stray_masks(rng):
     template = {"w": jnp.zeros(t, jnp.float32)}
     corr = masker.reconstruct(template, dropped, survivors, 4, "k")
     np.testing.assert_allclose(total, np.asarray(corr["w"]), atol=1e-4)
+
+
+# the solar forecaster's width, and an odd width that fills no whole tile
+SOLAR_T = 141_953
+
+
+@pytest.mark.parametrize("t", [SOLAR_T, 1_001])
+@pytest.mark.parametrize("n", [2, 5, 32])
+def test_full_member_set_masks_cancel(n, t):
+    """Every member's device-drawn mask, summed over the full set, is zero
+    up to float summation order."""
+    masker = PairwiseMasker(seed=2_147_483_659, mask_scale=1.0)
+    ids = [f"site-{i:03d}" for i in range(n)]
+    total = sum(masker.mask_flat(cid, ids, 7, "__global__", t) for cid in ids)
+    assert total.shape == (t,)
+    # each member's mask has std sqrt(n - 1): a missing pair would leave ~1
+    np.testing.assert_allclose(np.asarray(total), 0.0, atol=1e-4 * n)
+
+
+@pytest.mark.parametrize("n_dropped", [1, 3])
+def test_recovery_equals_survivors_stray_masks(n_dropped):
+    """The dealer's recovery equals the sum of what the survivors' masks
+    leave uncancelled against the dropped members."""
+    masker = PairwiseMasker(seed=11, mask_scale=1.5)
+    ids = [f"c{i}" for i in range(32)]
+    dropped, survivors = ids[5:5 + n_dropped], ids[:5] + ids[5 + n_dropped:]
+    stray = sum(masker.mask_flat(cid, ids, 4, "cluster/0", SOLAR_T)
+                for cid in survivors)
+    template = {"a": jnp.zeros((SOLAR_T - 1, 1)), "b": jnp.zeros(())}
+    corr = masker.reconstruct(template, dropped, survivors, 4, "cluster/0")
+    assert corr["a"].shape == (SOLAR_T - 1, 1) and corr["b"].shape == ()
+    np.testing.assert_allclose(np.asarray(flatten_params(corr)),
+                               np.asarray(stray), atol=1e-3)
+    assert float(jnp.std(stray)) > 1.0      # the strays are really there
+
+
+@pytest.mark.parametrize("change", [None, "round_id", "model_key", "seed"])
+def test_pair_mask_is_a_function_of_seed_pair_round_and_model(change):
+    """Two maskers give bit-identical masks for the same ``(seed, pair,
+    round, model key)``; changing the round, the model or the master seed
+    gives another mask."""
+    args = {"round_id": 3, "model_key": "__global__", "seed": 5}
+    a = PairwiseMasker(seed=args["seed"]).mask_flat(
+        "x", ["x", "y"], args["round_id"], args["model_key"], 1_001)
+    if change is not None:
+        args[change] = {"round_id": 4, "model_key": "cluster/1",
+                        "seed": 6}[change]
+    b = PairwiseMasker(seed=args["seed"]).mask_flat(
+        "x", ["x", "y"], args["round_id"], args["model_key"], 1_001)
+    if change is None:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    else:
+        assert not np.any(np.asarray(a) == np.asarray(b))
+
+
+@pytest.mark.parametrize("mask_scale", [1.0, 2.5])
+def test_pair_mask_is_a_gaussian_of_std_mask_scale(mask_scale):
+    m = PairwiseMasker(seed=8, mask_scale=mask_scale).mask_flat(
+        "a", ["a", "b"], 0, "__global__", SOLAR_T)
+    assert m.dtype == jnp.float32
+    m = np.asarray(m, np.float64)
+    # the mean of 141,953 draws has std ~0.0027 mask_scale
+    assert abs(m.mean()) < 0.015 * mask_scale
+    assert abs(m.std() / mask_scale - 1.0) < 0.01
+
+
+def test_member_and_dropout_counts_compile_no_program():
+    """After one two-member mask of width T, masks of 5 and 32 members and
+    a recovery of 3 dropped members reuse its compiled program."""
+    masker = PairwiseMasker(seed=1, mask_scale=1.0)
+    t = 4_099
+    delta = jnp.ones(t, jnp.float32)
+    masker.mask_delta_flat(delta, "c0", ["c0", "c1"], 0, "k", weight=2.0)
+    compiled = _pair_masks._cache_size()
+    ids = [f"c{i}" for i in range(32)]
+    masker.mask_delta_flat(delta, "c0", ids[:5], 1, "k", weight=2.0)
+    masker.mask_delta_flat(delta, "c3", ids, 1, "k", weight=2.0)
+    masker.reconstruct({"w": delta}, ids[:3], ids[3:], 1, "k")
+    assert _pair_masks._cache_size() == compiled
+
+
+def test_zero_mask_scale_draws_nothing(rng, monkeypatch):
+    """``mask_scale=0``: the weighted delta unmasked, a zero recovery, and
+    the mask program never runs."""
+    def drawn(*a, **k):
+        raise AssertionError("mask_scale 0, yet masks were drawn")
+
+    monkeypatch.setattr(secure_agg, "_pair_masks", drawn)
+    masker = PairwiseMasker(seed=1, mask_scale=0.0)
+    delta = jnp.asarray(rng.standard_normal(33), jnp.float32)
+    out = masker.mask_delta_flat(delta, "a", ["a", "b", "c"], 0, "k", 3.0)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(delta * 3.0))
+    corr = masker.reconstruct({"w": delta}, ["c"], ["a", "b"], 0, "k")
+    np.testing.assert_array_equal(np.asarray(corr["w"]), 0.0)
 
 
 # ------------------------------------------------------- store secure drain

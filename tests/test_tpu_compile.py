@@ -21,6 +21,7 @@ from repro.kernels.ewc_update.ewc_update import ewc_tiled
 from repro.kernels.fedavg_agg.fedavg_agg import TILE, agg_tiled
 from repro.kernels.lstm_cell.lstm_cell import BATCH_TILE, lstm_step_tiled
 from repro.models.lstm import SolarForecaster
+from repro.privacy.secure_agg import PAIRS_PER_CALL, _pair_masks
 from repro.training.fed_solar import make_solar_fns
 
 # a 141,953-float solar model pads to 18 kernel tiles
@@ -153,3 +154,14 @@ def test_held_expert_layer_and_causal_attention_compile_at_published_widths(shap
 
     compiled = jax.jit(jax.grad(attn_loss, (0, 1, 2))).lower(q, q, v).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("t", [141_953, 424_961_024])
+def test_pair_mask_program_compiles_in_o_of_t_memory(shape, t):
+    """The secure-aggregation mask program compiles for the chip at the
+    solar forecaster's width and the silo LM's, holding O(T) bytes: a
+    stack of a block's pair masks would need PAIRS_PER_CALL x T floats."""
+    rows = shape((PAIRS_PER_CALL, 8), jnp.uint32)
+    compiled = _pair_masks.lower(rows, t=t, scale=1.0).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 3 * 4 * t
